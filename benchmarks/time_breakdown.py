@@ -86,8 +86,6 @@ def breakdown(model: str, method: str, *, P=4, k=None, width=None,
         lambda p: cnn.ce_loss(apply(p, imgs), labs)))
     t_compu = _time(grad_fn, p0)
     ca = jax.jit(grad_fn).lower(p0).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     fwd_flops = (ca or {}).get("flops", 0.0)
     t_compu_model = max(fwd_flops / ACCEL_FLOPS, 1e-5)
 

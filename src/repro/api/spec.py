@@ -689,6 +689,11 @@ class RunSpec:
         False, "--smoke", const=True,
         surfaces=("train", "sim", "tune", "serve"),
         dest="smoke", help="use the reduced same-family config")
+    layers: int | None = _field(
+        None, "--layers", parse=parse_opt_int,
+        surfaces=("train", "sim", "tune", "serve"),
+        help="cut the model to this many layers, every width kept "
+             "('none' = the config's own depth)")
     d: int | None = _field(
         None, "--d", parse=parse_opt_int, surfaces=("sim", "tune"),
         help="flat gradient dimension override ('none' = derive from "
@@ -737,6 +742,8 @@ class RunSpec:
                 raise ValueError(f"{f} must be >= 1, got {getattr(self, f)}")
         if self.d is not None and self.d < 1:
             raise ValueError(f"d must be >= 1, got {self.d}")
+        if self.layers is not None and self.layers < 1:
+            raise ValueError(f"layers must be >= 1, got {self.layers}")
         self.exchange.validate()
         self.cluster.validate()
         self.watch.validate()
@@ -772,8 +779,12 @@ class RunSpec:
     # -- surface conversions ------------------------------------------------
 
     def arch_config(self):
+        """The arch's published (or smoke) config, cut to ``layers``."""
         from repro.configs import ARCHS, SMOKES
-        return (SMOKES if self.smoke else ARCHS)[self.arch]
+        cfg = (SMOKES if self.smoke else ARCHS)[self.arch]
+        if self.layers is not None:
+            cfg = dataclasses.replace(cfg, n_layers=int(self.layers))
+        return cfg
 
     def mesh_axes(self):
         from repro.core.gs_sgd import MeshAxes

@@ -37,6 +37,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.count_sketch import SketchConfig
 from repro.kernels.dispatch import default_interpret
+from repro.kernels.sketch_decode import gather_rows, median_rows
+from repro.kernels.sketch_encode import lane_block
 
 Array = jax.Array
 
@@ -46,42 +48,16 @@ _BIG = 1e30  # matches heavymix._BIG — the heavy-set priority boost
 def _scores_kernel(hash_ref, sk_ref, thr_ref, score_ref, est_ref, acc_ref, *,
                    rows: int, block_d: int, block_w: int, shift: int,
                    n_w: int):
-    i = pl.program_id(0)  # coordinate block (outer)
-    j = pl.program_id(1)  # bucket block (inner, accumulation axis)
+    gather_rows(hash_ref, sk_ref, acc_ref, rows=rows, block_d=block_d,
+                block_w=block_w, shift=shift, index_offset=0)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    idx = (jax.lax.broadcasted_iota(jnp.uint32, (block_d, block_w), 0)
-           + jnp.uint32(i * block_d))
-    col = (jax.lax.broadcasted_iota(jnp.uint32, (block_d, block_w), 1)
-           + jnp.uint32(j * block_w))
-
-    acc = acc_ref[...]
-    for r in range(rows):  # R is small & static — unrolled
-        a = hash_ref[r, 0]
-        b = hash_ref[r, 1]
-        c = hash_ref[r, 2]
-        d_ = hash_ref[r, 3]
-        bucket = (a * idx + b) >> jnp.uint32(shift)
-        sign = 1.0 - 2.0 * ((c * idx + d_) >> jnp.uint32(31)).astype(jnp.float32)
-        onehot = jnp.where(bucket == col, sign, 0.0)  # (B, BW)
-        row = sk_ref[r, :].astype(jnp.float32).reshape(block_w, 1)
-        gathered = jnp.dot(onehot, row, preferred_element_type=jnp.float32)
-        acc = acc.at[r, :].add(gathered[:, 0])
-    acc_ref[...] = acc
-
-    @pl.when(j == n_w - 1)
+    @pl.when(pl.program_id(1) == n_w - 1)
     def _finalize():
-        srt = jnp.sort(acc_ref[...], axis=0)  # (R, B) sorted per coordinate
-        if rows % 2 == 1:
-            est = srt[rows // 2, :]
-        else:
-            est = 0.5 * (srt[rows // 2 - 1, :] + srt[rows // 2, :])
+        est = median_rows([acc_ref[r:r + 1, :] for r in range(rows)])
         heavy = (est * est >= thr_ref[0, 0]).astype(jnp.float32)
-        est_ref[...] = est
-        score_ref[...] = jnp.abs(est) + _BIG * heavy
+        est_ref[...] = est.reshape(block_d // 128, 128)
+        score_ref[...] = (jnp.abs(est) + _BIG * heavy).reshape(
+            block_d // 128, 128)
 
 
 @functools.partial(
@@ -99,9 +75,8 @@ def heavymix_scores(cfg: SketchConfig, sketch: Array, thresh: Array, d: int,
     ``kernels.ops.heavymix_recover`` for the dispatched entry.
     """
     interpret = default_interpret(interpret)
-    block_d = min(block_d, max(8, d))
+    block_d, d_pad = lane_block(d, block_d)
     block_w = min(block_w, cfg.width)
-    d_pad = d + ((-d) % block_d)
     n_d = d_pad // block_d
     w_pad = cfg.width + ((-cfg.width) % block_w)  # same pad as sketch_decode
     n_w = w_pad // block_w
@@ -124,14 +99,14 @@ def heavymix_scores(cfg: SketchConfig, sketch: Array, thresh: Array, d: int,
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_d,), lambda i, j: (i,)),
-            pl.BlockSpec((block_d,), lambda i, j: (i,)),
+            pl.BlockSpec((block_d // 128, 128), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_d // 128, 128), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((d_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((d_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((d_pad // 128, 128), jnp.float32),
+            jax.ShapeDtypeStruct((d_pad // 128, 128), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((cfg.rows, block_d), jnp.float32)],
         interpret=interpret,
     )(hash_params, sk, thr)
-    return scores[:d], est[:d]
+    return scores.reshape(-1)[:d], est.reshape(-1)[:d]

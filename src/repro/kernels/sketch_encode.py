@@ -15,8 +15,15 @@ gradient streams through. Per grid step the kernel
   1. recomputes bucket ids / signs for the element block with branch-free
      multiply-shift hashes (uint32 vector ALU),
   2. materializes the (block_d, block_w) signed one-hot tile,
-  3. contracts (1, block_d) @ (block_d, block_w) on the MXU,
-  4. accumulates into the (R, block_w) output tile (f32).
+  3. contracts (3, block_d) @ (block_d, block_w) on the MXU: the gradient
+     block split into three bf16 parts (``split_bf16``),
+  4. accumulates the parts' sum into the (R, block_w) output tile (f32).
+
+The MXU rounds f32 operands to bf16 at default precision, which on a v5e
+cost the sketch about 2e-3 of its relative accuracy. The one-hot tile is
+exact in bf16 (0, +-1), and the three bf16 parts of ``g`` sum exactly to
+``g``, so one bf16 matmul with three LHS rows and f32 accumulation gives
+f32 products for the MXU price of one row.
 
 VMEM per step ~= block_d * block_w * 4 B (one-hot tile) + R * block_w * 4 B
 (accumulator) + block_d * 4 B (gradient block): 2.1 MB at the 1024x512
@@ -29,9 +36,15 @@ full encode, which is how the fused backward-interleaved pipeline
 (DESIGN.md §7) consumes gradient chunks incrementally instead of waiting
 for a bucket's full range.
 
-FLOP cost is 2*d*W*R MACs (the price of scatter-free encoding); for the
-sketch sizes gs-SGD uses (W ~ 2^14..2^17) this is a small fraction of the
-model's backward FLOPs — quantified in benchmarks/time_breakdown.py.
+FLOP cost is 2*d*W*R MACs (the price of scatter-free encoding). Its share
+of a training step on the chip is not measured.
+
+Mosaic (the TPU compiler) constraints the code follows: ``g`` arrives as a
+lane-dense ``(d_pad // 128, 128)`` array, so every block — including the
+``(P, ...)`` block a ``vmap`` over workers adds — has (8, 128)-aligned
+trailing dims; the sign bit is cast through int32 (no uint32 -> f32 cast);
+and each row's contribution is added into the output ref in place (no
+value-level scatter-add).
 """
 
 from __future__ import annotations
@@ -48,6 +61,36 @@ from repro.kernels.dispatch import default_interpret
 Array = jax.Array
 
 
+def signed_onehot(hash_ref, r: int, idx: Array, col: Array,
+                  shift: int) -> Array:
+    """Row ``r``'s signed one-hot tile: ``sign_r(idx)`` where
+    ``h_r(idx) == col``, else 0 (bf16, exact; the shape of ``idx``/``col``).
+
+    Shared by the encode, decode and HEAVYMIX kernels; the hash is the
+    multiply-shift of ``core.count_sketch.hash_buckets``."""
+    a = hash_ref[r, 0]
+    b = hash_ref[r, 1]
+    c = hash_ref[r, 2]
+    d_ = hash_ref[r, 3]
+    bucket = (a * idx + b) >> jnp.uint32(shift)
+    bit = ((c * idx + d_) >> jnp.uint32(31)).astype(jnp.int32)
+    sign = 1.0 - 2.0 * bit.astype(jnp.float32)
+    return jnp.where(bucket == col, sign, 0.0).astype(jnp.bfloat16)
+
+
+def split_bf16(x: Array) -> Array:
+    """``(1, n)`` f32 -> ``(3, n)`` bf16 rows that sum exactly to ``x``.
+
+    Each part takes the next 8 significant bits of the remainder (bf16
+    keeps f32's exponent range), so three parts hold all 24 bits of an
+    f32 significand."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=0)
+
+
 def _encode_kernel(hash_ref, g_ref, out_ref, *, rows: int, block_d: int,
                    block_w: int, shift: int, index_offset: int):
     j = pl.program_id(0)  # bucket-column block (outer)
@@ -57,7 +100,8 @@ def _encode_kernel(hash_ref, g_ref, out_ref, *, rows: int, block_d: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    g = g_ref[...].astype(jnp.float32).reshape(1, block_d)  # (1, B)
+    # (block_d // 128, 128) lane-dense block -> (1, block_d) -> 3 bf16 parts
+    g3 = split_bf16(g_ref[...].astype(jnp.float32).reshape(1, block_d))
 
     # Element index for every (element, bucket) cell; uniform across columns.
     idx = (jax.lax.broadcasted_iota(jnp.uint32, (block_d, block_w), 0)
@@ -66,18 +110,20 @@ def _encode_kernel(hash_ref, g_ref, out_ref, *, rows: int, block_d: int,
     col = (jax.lax.broadcasted_iota(jnp.uint32, (block_d, block_w), 1)
            + jnp.uint32(j * block_w))
 
-    acc = out_ref[...]
     for r in range(rows):  # R is small & static — unrolled
-        a = hash_ref[r, 0]
-        b = hash_ref[r, 1]
-        c = hash_ref[r, 2]
-        d_ = hash_ref[r, 3]
-        bucket = (a * idx + b) >> jnp.uint32(shift)
-        sign = 1.0 - 2.0 * ((c * idx + d_) >> jnp.uint32(31)).astype(jnp.float32)
-        onehot = jnp.where(bucket == col, sign, 0.0)  # (B, BW) signed one-hot
-        contrib = jnp.dot(g, onehot, preferred_element_type=jnp.float32)  # (1, BW)
-        acc = acc.at[r, :].add(contrib[0])
-    out_ref[...] = acc
+        onehot = signed_onehot(hash_ref, r, idx, col, shift)  # (B, BW)
+        parts = jnp.dot(g3, onehot, preferred_element_type=jnp.float32)
+        out_ref[r:r + 1, :] += jnp.sum(parts, axis=0, keepdims=True)
+
+
+def lane_block(d: int, block_d: int) -> tuple[int, int]:
+    """(block_d, d_pad) for a length-``d`` vector held as ``(d_pad // 128,
+    128)``: the block shrinks to cover a short vector and stays a multiple
+    of 128 lanes; ``d_pad`` is a whole number of blocks."""
+    if block_d % 128:
+        raise ValueError(f"block_d must be a multiple of 128, got {block_d}")
+    block_d = min(block_d, -(-d // 128) * 128)
+    return block_d, -(-d // block_d) * block_d
 
 
 @functools.partial(
@@ -99,12 +145,11 @@ def sketch_encode(cfg: SketchConfig, g: Array, *, index_offset: int = 0,
     interpret = default_interpret(interpret)
     g = g.reshape(-1)
     d = g.shape[0]
-    block_d = min(block_d, max(8, d))
+    block_d, d_pad = lane_block(d, block_d)
     block_w = min(block_w, cfg.width)
-    pad = (-d) % block_d
-    if pad:
-        g = jnp.pad(g, (0, pad))  # zero elements contribute nothing
-    n_d = g.shape[0] // block_d
+    if d_pad != d:
+        g = jnp.pad(g, (0, d_pad - d))  # zero elements contribute nothing
+    n_d = d_pad // block_d
     # Pad the bucket axis up to a block_w multiple: bucket ids are < width,
     # so the padded columns never match and stay zero (sliced off below).
     # Without this, a width not divisible by block_w silently DROPPED the
@@ -122,12 +167,12 @@ def sketch_encode(cfg: SketchConfig, g: Array, *, index_offset: int = 0,
         grid=(n_w, n_d),
         in_specs=[
             pl.BlockSpec((cfg.rows, 4), lambda j, i: (0, 0)),
-            pl.BlockSpec((block_d,), lambda j, i: (i,)),
+            pl.BlockSpec((block_d // 128, 128), lambda j, i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((cfg.rows, block_w), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((cfg.rows, w_pad), jnp.float32),
         interpret=interpret,
-    )(hash_params, g)
+    )(hash_params, g.reshape(d_pad // 128, 128))
     return out[:, :cfg.width] if w_pad != cfg.width else out
 
 

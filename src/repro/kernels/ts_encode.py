@@ -47,12 +47,11 @@ def _kernel(sign_ref, g_ref, out_ref, *, rows: int, width: int,
     i0 = jnp.uint32(i) * jnp.uint32(width)
     idx = jax.lax.iota(jnp.uint32, width) + i0
 
-    acc = out_ref[...]
     for r in range(rows):                                  # static unroll
         cmul = sign_ref[r, 0]
         cadd = sign_ref[r, 1]
-        sign = 1.0 - 2.0 * ((cmul * idx + cadd) >> jnp.uint32(31)).astype(
-            jnp.float32)
+        bit = ((cmul * idx + cadd) >> jnp.uint32(31)).astype(jnp.int32)
+        sign = 1.0 - 2.0 * bit.astype(jnp.float32)
         y = g * sign
         a = log_m[r]
         n_log = bits - a
@@ -65,8 +64,7 @@ def _kernel(sign_ref, g_ref, out_ref, *, rows: int, width: int,
         sums = y.reshape(n, width >> n_log).sum(axis=0)    # (W/n,)
         placed = jnp.zeros((width >> n_log, n), jnp.float32) \
             .at[:, 0].set(sums).reshape(width)
-        acc = acc.at[r, :].add(jnp.roll(placed, c))
-    out_ref[...] = acc
+        out_ref[r, :] += jnp.roll(placed, c)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))

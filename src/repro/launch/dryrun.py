@@ -36,6 +36,7 @@ from repro.configs import ARCHS, DP_MODE, TRAIN_OVERRIDES
 from repro.configs.shapes import SHAPES, applicable, skip_reason
 from repro.core.gs_sgd import (MeshAxes, make_serve_fns, make_train_step)
 from repro.launch import specs as sp
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_production_mesh, mesh_axes_of
 from repro.models.flatten import make_flat_spec
 from repro.optim import make as make_opt
@@ -262,6 +263,7 @@ def main() -> None:
                     choices=["single", "multi", "both"])
     ap.add_argument("--all", action="store_true")
     args = ap.parse_args()
+    configure_compile_cache()
 
     archs = [args.arch] if args.arch else list(ARCHS)
     shapes = [args.shape] if args.shape else list(SHAPES)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import api
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.common import ShardCtx
 from repro.models.flatten import init_flat_params, make_flat_spec
 from repro.serve import Request, ServeEngine
@@ -98,6 +99,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--json", default="BENCH_serve.json", metavar="PATH",
                     help="load-test report path")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     base = api.RunSpec.load(args.spec) if args.spec \
         else api.RunSpec(smoke=True)

@@ -35,6 +35,7 @@ import time
 
 from repro import api
 from repro.api import RunSpec
+from repro.launch.compile_cache import configure_compile_cache
 from repro.sim import FaultTrace, TraceEvent, simulate, synthetic
 
 
@@ -145,6 +146,7 @@ def main(argv=None) -> dict:
                          "as benchmarks/comm_complexity.py: model/curves/"
                          "checks) for CI diffing")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     if args.spec and args.plan:
         ap.error("--spec and --plan both name a base spec; pass one")
 

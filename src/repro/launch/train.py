@@ -8,15 +8,20 @@ merges a ``repro.launch.tune`` plan's exchange config into the base spec
 through the same path the manual flags take — pinned bit-exact against
 passing ``plan.train_argv()`` by hand.
 
-Two execution modes:
+Where the P = ``--workers`` data-parallel workers run follows from the
+device count, not from a flag (``make_step_fn``):
 
-  --mode sim   (default on this CPU container) — P data-parallel workers are
-               simulated with ``jax.vmap(step, axis_name='data')``: the
-               collective semantics (psum / ppermute tree / all_gather) are
-               bit-identical to a real mesh, so convergence results carry.
-  --mode mesh  — run the same step under jax.shard_map on whatever devices
-               exist (set XLA_FLAGS=--xla_force_host_platform_device_count=N
-               to emulate; on TPU this is the production path).
+  * at least P devices: one worker per device. The step runs under
+    ``jax.shard_map`` on a ``("data",)`` mesh of the first P devices, and
+    the ``(P, ...)`` state and batch are sharded on their leading axis, so
+    the sketch merge is a real cross-device collective.
+  * fewer devices: the P workers share device 0 under
+    ``jax.vmap(step, axis_name="data")``. The collectives (psum, ppermute
+    tree, all_gather) have the same semantics, so the two give the same
+    numbers.
+
+Either way the state is donated to the jitted step, so the old and new
+state are never live together.
 
 Fault tolerance: checkpoints every --ckpt-every steps (atomic, keep-N,
 async), resumes bit-exact with --resume (the data cursor is the step
@@ -39,12 +44,15 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro import api, obs
 from repro import ckpt as ckpt_lib
 from repro.api import RunSpec
 from repro.core.gs_sgd import make_state
 from repro.data import LMStream
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.flatten import init_flat_params
 
 
@@ -64,6 +72,76 @@ def build(spec: RunSpec):
         print(f"backward-interleaved readiness: {ts.bwd_chunks} chunk(s), "
               f"bucket readiness {ready}")
     return cfg, opt, ma, ts
+
+
+def workers_on_mesh(P: int) -> bool:
+    """True when P > 1 workers get a device each (module docstring)."""
+    return 1 < P <= len(jax.devices())
+
+
+def make_step_fn(ts, P: int):
+    """Jit ``ts.fn`` over P workers with the state donated: one worker per
+    device on a ``("data",)`` mesh when ``workers_on_mesh(P)``, else
+    vmapped on one device. State, batch and metrics carry a leading P axis
+    when P > 1."""
+    if P == 1:
+        return jax.jit(ts.fn, donate_argnums=0)
+    if not workers_on_mesh(P):
+        return jax.jit(jax.vmap(ts.fn, axis_name="data"), donate_argnums=0)
+    sharded = worker_sharding(P)
+    lead = sharded.spec
+
+    def per_device(state, batch):
+        # each device holds a (1, ...) block of the (P, ...) layout
+        one = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)  # noqa: E731
+        out = ts.fn(one(state), one(batch))
+        return jax.tree_util.tree_map(lambda a: a[None], out)
+
+    return jax.jit(
+        jax.shard_map(per_device, mesh=sharded.mesh, in_specs=(lead, lead),
+                      out_specs=(lead, lead), check_vma=False),
+        in_shardings=(sharded, sharded), out_shardings=(sharded, sharded),
+        donate_argnums=0)
+
+
+def worker_sharding(P: int) -> NamedSharding:
+    """The ``(P, ...)`` layout on the mesh path: leading axis over a
+    ``("data",)`` mesh of the first P devices."""
+    mesh = Mesh(np.array(jax.devices()[:P]), ("data",))
+    return NamedSharding(mesh, PartitionSpec("data"))
+
+
+def init_state(spec: RunSpec, cfg, opt, ts):
+    """Step-0 state from ``spec.seed``: every worker starts from the same
+    replica, stacked on a leading P axis when P > 1. On the mesh path each
+    device receives only its own worker's block, so no device ever holds
+    the P stacked copies."""
+    params = init_flat_params(cfg, jax.random.PRNGKey(spec.seed), 1, ts.fs)
+    state = make_state(params, opt, ts.compressor, ts.d_local)
+    P = spec.cluster.p
+    if P > 1 and workers_on_mesh(P):
+        sharded = worker_sharding(P)
+        devices = list(sharded.mesh.devices.flat)
+
+        def stack(a):
+            blocks = [jax.device_put(a[None], d) for d in devices]
+            return jax.make_array_from_single_device_arrays(
+                (P,) + a.shape, sharded, blocks)
+        state = jax.tree_util.tree_map(stack, state)
+    elif P > 1:
+        state = jax.tree_util.tree_map(
+            lambda a: jnp.broadcast_to(a, (P,) + a.shape), state)
+    return state
+
+
+def worker_batch(stream: LMStream, step: int, spec: RunSpec) -> dict:
+    """The global batch of ``step``, split into P worker rows when P > 1."""
+    gb = stream.global_batch_at(step)
+    P = spec.cluster.p
+    if P == 1:
+        return gb
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((P, spec.batch // P) + a.shape[1:]), gb)
 
 
 def resolve_spec(args) -> RunSpec:
@@ -190,6 +268,7 @@ def main(argv=None) -> dict:
                     help="simulate a crash after this step (tests)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     spec = resolve_spec(args)
     if args.dump_spec:
@@ -209,14 +288,12 @@ def main(argv=None) -> dict:
     stream = LMStream(vocab_size=cfg.vocab_size, seq_len=spec.seq,
                       global_batch=spec.batch, seed=spec.seed)
 
-    params = init_flat_params(cfg, jax.random.PRNGKey(spec.seed), 1, ts.fs)
-    state = make_state(params, opt, ts.compressor, ts.d_local)
+    state = init_state(spec, cfg, opt, ts)
+    step_fn = make_step_fn(ts, P)
     if P > 1:
-        state = jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a, (P,) + a.shape), state)
-        step_fn = jax.jit(jax.vmap(ts.fn, axis_name="data"))
-    else:
-        step_fn = jax.jit(ts.fn)
+        print(f"{P} workers: " + ("one per device on a 'data' mesh"
+                                  if workers_on_mesh(P)
+                                  else "vmapped on one device"))
 
     start = 0
     saver = None
@@ -295,12 +372,7 @@ def main(argv=None) -> dict:
     t0 = time.time()
     replanned_at = None   # next step recompiles -> tag it warmup
     for step in range(start, spec.steps):
-        gb = stream.global_batch_at(step)
-        if P > 1:
-            batch = jax.tree_util.tree_map(
-                lambda a: a.reshape((P, spec.batch // P) + a.shape[1:]), gb)
-        else:
-            batch = gb
+        batch = worker_batch(stream, step, spec)
         if step == probe_at:
             # eager (un-jitted) replay of this step's inputs: per-phase
             # spans fire as ops dispatch; the result is DISCARDED, so the
@@ -368,8 +440,7 @@ def main(argv=None) -> dict:
                     print("watchdog: error-feedback reset "
                           "(exchange geometry changed)")
                     state = {**state, "ef": new_ef}
-                step_fn = (jax.jit(jax.vmap(ts.fn, axis_name="data"))
-                           if P > 1 else jax.jit(ts.fn))
+                step_fn = make_step_fn(ts, P)
                 if args.json:
                     from repro.core import compression as comp
                     stats = comp.static_comm_stats(ts.compressor,

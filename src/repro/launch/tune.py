@@ -29,6 +29,7 @@ import time
 
 from repro import api
 from repro.api import RunSpec
+from repro.launch.compile_cache import configure_compile_cache
 from repro.tune import SearchSpace, TunePlan, fit, load_trace, search
 
 
@@ -99,6 +100,7 @@ def main(argv=None) -> TunePlan:
                          "buckets/widths to make alpha/beta identifiable")
     ap.add_argument("--out", default=None, metavar="PLAN.json")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     base = RunSpec.load(args.spec) if args.spec else RunSpec()
     spec = api.apply_args(base, args, "tune")

@@ -45,6 +45,34 @@ def test_runspec_file_round_trip_and_schema_guard(tmp_path):
         RunSpec.load(str(tmp_path / "junk.json"))
 
 
+def test_layers_cut_round_trips_and_keeps_every_width(tmp_path):
+    """``RunSpec.layers`` cuts depth only: it survives the spec JSON, the
+    generated ``--layers`` flag sets and resets it, and ``arch_config()``
+    differs from the published config in ``n_layers`` alone."""
+    from repro import obs
+    from repro.configs import ARCHS
+    spec = RunSpec(arch="musicgen-large", layers=3)
+    path = str(tmp_path / "spec.json")
+    spec.save(path)
+    assert RunSpec.load(path) == spec
+    assert json.loads(open(path).read())["layers"] == 3
+    ap = build_parser("train")
+    assert apply_args(RunSpec(arch="musicgen-large"),
+                      ap.parse_args(["--layers", "3"]), "train") == spec
+    assert apply_args(spec, ap.parse_args(["--layers", "none"]),
+                      "train").layers is None
+    pub = ARCHS["musicgen-large"]
+    assert spec.arch_config().n_layers == 3
+    assert dataclasses.replace(spec.arch_config(),
+                               n_layers=pub.n_layers) == pub
+    assert RunSpec(arch="musicgen-large").arch_config() == pub
+    assert (obs.provenance(spec)["runspec_sha256"]
+            != obs.provenance(RunSpec(arch="musicgen-large"))
+            ["runspec_sha256"])
+    with pytest.raises(ValueError, match="layers"):
+        RunSpec(layers=0).validate()
+
+
 # ---------------------------------------------------------------------------
 # the one default table: spec defaults == library defaults == CLI defaults
 # ---------------------------------------------------------------------------

@@ -93,21 +93,12 @@ batch_specs = {"tokens": P(("pod", "data"), None),
                "labels": P(("pod", "data"), None)}
 gbatch = {k: jax.device_put(v, jax.NamedSharding(mesh, batch_specs[k]))
           for k, v in batch.items()}
-# jax-version compat: top-level jax.shard_map + check_vma landed after
-# 0.4.x; fall back to jax.experimental.shard_map (check_rep) there.
-import inspect
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_chk = ("check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-        else "check_rep")
-step = jax.jit(_shard_map(
+step = jax.jit(jax.shard_map(
     ts.fn, mesh=mesh, in_specs=(state_specs, batch_specs),
     out_specs=(state_specs, {"loss": P(), "grad_norm": P()}),
-    **{_chk: False}))
+    check_vma=False))
 mesh_losses = []
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     for _ in range(3):
         state, m = step(state, gbatch)
         mesh_losses.append(float(m["loss"]))
