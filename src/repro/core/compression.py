@@ -41,6 +41,7 @@ from repro.core import count_sketch as cs
 from repro.core import error_feedback as ef
 from repro.core import heavymix as hm
 from repro.kernels import ops as kops
+from repro.obs import trace as obtrace
 
 Array = jax.Array
 AxisNames = str | Sequence[str]
@@ -92,7 +93,8 @@ class DenseAllReduce:
 
     def step(self, state, g: Array, *, axis: AxisNames, nworkers: int,
              key: Array | None = None):
-        upd = jax.lax.psum(g.astype(jnp.float32), axis)
+        with obtrace.phase("comm", "allreduce"):
+            upd = jax.lax.psum(g.astype(jnp.float32), axis)
         stats = self.comm_stats(g.size, nworkers)
         return upd, state, stats
 
@@ -220,15 +222,17 @@ class _SketchBased:
         est = None
         if self.encoder == "ts":
             from repro.core import ts_sketch as ts
-            est = ts.decode(self._ts_cfg(d), sketch_sum, d)
+            with jax.named_scope("recover/decode"):
+                est = ts.decode(self._ts_cfg(d), sketch_sum, d)
         idx, _ = hm.heavymix(self.sketch, sketch_sum, self.k, d, key=key,
                              faithful=self.faithful_heavymix, estimates=est)
         # Second round (Alg.2 line 4): exact values of Top_k, k floats.
-        vals = u[idx] if include is None else u[idx] * include
-        vals = jax.lax.psum(vals, axis)
-        if scale is not None:
-            vals = vals * scale
-        return _scatter(d, idx, vals), idx
+        with jax.named_scope("recover/second_round"):
+            vals = u[idx] if include is None else u[idx] * include
+            vals = jax.lax.psum(vals, axis)
+            if scale is not None:
+                vals = vals * scale
+            return _scatter(d, idx, vals), idx
 
 
 @jax.tree_util.register_static
@@ -250,13 +254,17 @@ class SketchedSGD(_SketchBased):
 
     def step(self, acc: Array, g: Array, *, axis: AxisNames, nworkers: int,
              key: Array | None = None):
-        u = ef.add(acc, g)
+        with obtrace.phase("encode"):
+            u = ef.add(acc, g)
+            sk = self._encode(u)
         d = u.shape[0]
-        sk = self._encode(u)
-        gathered = jax.lax.all_gather(sk, axis)  # (P, R, W) — the PS inbox
-        sk_sum = jnp.sum(gathered.reshape(-1, *sk.shape), axis=0)
-        upd, idx = self._recover(sk_sum, u, d, axis=axis, key=key)
-        acc = ef.residual_global(u, idx)
+        with obtrace.phase("comm", "allgather"):
+            gathered = jax.lax.all_gather(sk, axis)  # (P, R, W): PS inbox
+            sk_sum = jnp.sum(gathered.reshape(-1, *sk.shape), axis=0)
+        with obtrace.phase("recover"):
+            upd, idx = self._recover(sk_sum, u, d, axis=axis, key=key)
+            with jax.named_scope("recover/second_round"):
+                acc = ef.residual_global(u, idx)
         return upd, acc, self.comm_stats(d, nworkers)
 
 
@@ -308,9 +316,10 @@ class GsSGD(_SketchBased):
         """Stage 1, one fragment: EF add + partial encode of the bucket
         slice [offset, offset + len(g_piece)). Returns (u_piece, partial
         f32 sketch); ``stage_encode_merge`` assembles the bucket."""
-        u_piece = ef.add(acc_piece, g_piece)
-        sk = kops.encode(self.sketch, u_piece, offset=int(offset),
-                         use_pallas=self.use_pallas or None)
+        with jax.named_scope("encode"):
+            u_piece = ef.add(acc_piece, g_piece)
+            sk = kops.encode(self.sketch, u_piece, offset=int(offset),
+                             use_pallas=self.use_pallas or None)
         return u_piece, sk
 
     def stage_encode_merge(self, pieces) -> tuple[Array, Array]:
@@ -364,10 +373,11 @@ class GsSGD(_SketchBased):
         inc = include.astype(jnp.float32) if include is not None else None
         upd, idx = self._recover(sk_sum, u, d, axis=axis, key=key,
                                  include=inc, scale=scale)
-        if include is None:
-            acc = ef.residual_global(u, idx)
-        else:  # dropped workers keep their entire update for next step
-            acc = jnp.where(inc > 0, ef.residual_global(u, idx), u)
+        with jax.named_scope("recover/second_round"):
+            if include is None:
+                acc = ef.residual_global(u, idx)
+            else:  # dropped workers keep their entire update for next step
+                acc = jnp.where(inc > 0, ef.residual_global(u, idx), u)
         return upd, acc, self.comm_stats(d, nworkers)
 
     def comm_stats(self, d: int, nworkers: int) -> CommStats:
@@ -384,11 +394,20 @@ class GsSGD(_SketchBased):
 
     def step(self, acc: Array, g: Array, *, axis: AxisNames, nworkers: int,
              key: Array | None = None, include: Array | None = None):
-        u, sk = self.stage_encode(acc, g)
-        sk_sum, scale = self.stage_reduce(sk, axis=axis, nworkers=nworkers,
-                                          include=include)
-        return self.stage_recover(u, sk_sum, scale, axis=axis,
-                                  nworkers=nworkers, key=key, include=include)
+        with obtrace.phase("encode") as sp:
+            u, sk = self.stage_encode(acc, g)
+            sp.sync(sk)
+        with obtrace.phase("comm", "allreduce") as sp:
+            sk_sum, scale = self.stage_reduce(sk, axis=axis,
+                                              nworkers=nworkers,
+                                              include=include)
+            sp.sync(sk_sum)
+        with obtrace.phase("recover") as sp:
+            out = self.stage_recover(u, sk_sum, scale, axis=axis,
+                                     nworkers=nworkers, key=key,
+                                     include=include)
+            sp.sync(out[0])
+        return out
 
 
 @jax.tree_util.register_static
@@ -425,12 +444,17 @@ class FetchSGDStyle(_SketchBased):
              key: Array | None = None):
         s_m, s_e = state
         d = g.shape[0]
-        sk = jax.lax.psum(self._encode(g), axis)       # merged grad sketch
-        s_m = self.momentum * s_m + sk                 # momentum in-sketch
-        s_e = s_e + s_m                                # error accumulation
-        idx, est = hm.heavymix(self.sketch, s_e, self.k, d, key=key)
-        upd = _scatter(d, idx, est)
-        s_e = s_e - self._encode(upd)                  # subtract applied
+        with obtrace.phase("encode"):
+            sk = self._encode(g)
+        with obtrace.phase("comm", "allreduce"):
+            sk = jax.lax.psum(sk, axis)                # merged grad sketch
+        with obtrace.phase("recover"):
+            s_m = self.momentum * s_m + sk             # momentum in-sketch
+            s_e = s_e + s_m                            # error accumulation
+            idx, est = hm.heavymix(self.sketch, s_e, self.k, d, key=key)
+            upd = _scatter(d, idx, est)
+        with obtrace.phase("encode", "encode/applied"):
+            s_e = s_e - self._encode(upd)              # subtract applied
         return upd, (s_m, s_e), self.comm_stats(d, nworkers)
 
 

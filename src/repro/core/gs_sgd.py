@@ -171,27 +171,26 @@ def exchange_bucketed(bc: "comp.BucketedCompressor", ef_state, g_flat,
         return bc.step(ef_state, g_flat, axis=axis, nworkers=nworkers,
                        key=key, **kw)
 
-    tr = obtrace.current()
     parts = bc.spec.split(g_flat)
     keys = [None if key is None else jax.random.fold_in(key, i)
             for i in range(n)]
     us: list = [None] * n
     sks: list = [None] * n
     outs: list = [None] * n
-    with tr.span("encode/b0", cat="encode") as sp:
+    with obtrace.phase("encode", "encode/b0") as sp:
         us[0], sks[0] = bc.parts[0].stage_encode(ef_state[0], parts[0])
         sp.sync(sks[0])
     for i in range(n):
-        with tr.span(f"allreduce/b{i}", cat="comm") as sp:
+        with obtrace.phase("comm", f"allreduce/b{i}") as sp:
             sk_sum, scale = bc.parts[i].stage_reduce(
                 sks[i], axis=axis, nworkers=nworkers, include=include)
             sp.sync(sk_sum)
         if i + 1 < n:  # next bucket's encode — independent of the reduce
-            with tr.span(f"encode/b{i + 1}", cat="encode") as sp:
+            with obtrace.phase("encode", f"encode/b{i + 1}") as sp:
                 us[i + 1], sks[i + 1] = bc.parts[i + 1].stage_encode(
                     ef_state[i + 1], parts[i + 1])
                 sp.sync(sks[i + 1])
-        with tr.span(f"recover/b{i}", cat="recover") as sp:
+        with obtrace.phase("recover", f"recover/b{i}") as sp:
             outs[i] = bc.parts[i].stage_recover(
                 us[i], sk_sum, scale, axis=axis, nworkers=nworkers,
                 key=keys[i], include=include)
@@ -298,7 +297,7 @@ def exchange_interleaved(bc: "comp.BucketedCompressor", plan: BucketPlan,
     def recover(i: int) -> None:
         kb = (key if key is None or n == 1
               else jax.random.fold_in(key, i))
-        with tr.span(f"recover/b{i}", cat="recover") as sp:
+        with obtrace.phase("recover", f"recover/b{i}") as sp:
             outs[i] = parts[i].stage_recover(
                 us[i], sk_sum[i], scale[i], axis=axis, nworkers=nworkers,
                 key=kb, include=include)
@@ -325,14 +324,14 @@ def exchange_interleaved(bc: "comp.BucketedCompressor", plan: BucketPlan,
         for i in by_event.get(ev, []):
             tr.instant(f"ready/b{i}", cat="encode",
                        args={"bucket": i, "event": ev})
-            with tr.span(f"encode/b{i}", cat="encode") as sp:
+            with obtrace.phase("encode", f"encode/b{i}") as sp:
                 if fusable[i]:
                     us[i], sk = parts[i].stage_encode_merge(frags[i])
                 else:
                     us[i], sk = parts[i].stage_encode(ef_state[i],
                                                       assemble(i))
                 sp.sync(sk)
-            with tr.span(f"allreduce/b{i}", cat="comm") as sp:
+            with obtrace.phase("comm", f"allreduce/b{i}") as sp:
                 sk_sum[i], scale[i] = parts[i].stage_reduce(
                     sk, axis=axis, nworkers=nworkers, include=include)
                 sp.sync(sk_sum[i])
@@ -529,8 +528,10 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
         inv_tp = 1.0 / ma.tp
 
         def loss_of(p, b):
-            return inv_tp * mdl.loss_fn(cfg, ctx, fs, p, b, gathers=gathers,
-                                        remat=remat)
+            # inside autodiff: the backward is this scope's transpose
+            with jax.named_scope("forward"):
+                return inv_tp * mdl.loss_fn(cfg, ctx, fs, p, b,
+                                            gathers=gathers, remat=remat)
 
         tr = obtrace.current()
         b_loc = batch["tokens"].shape[0]
@@ -539,7 +540,7 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
         if bwd_chunks is not None:
             # Chunked backward: per-chunk VJPs emit gradient slices in
             # reverse order (seeded with 1/tp, mirroring loss_of's scaling)
-            with tr.span("forward", cat="forward") as sp:
+            with obtrace.phase("forward") as sp:
                 loss, bwd_steps, top_grads = mdl.chunked_loss_vjp(
                     cfg, ctx, fs, params, batch, chunks=bwd_chunks,
                     gathers=gathers, remat=remat, grad_seed=inv_tp)
@@ -548,7 +549,9 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
             grads = None
         elif mb >= b_loc:
             # monolithic autodiff: forward and backward are one fused
-            # call, so the span carries both under cat='backward'
+            # call, so the span carries both under cat='backward'; it
+            # enters no scope (loss_of names the forward, and the
+            # backward is its transpose)
             with tr.span("loss_and_grad", cat="backward") as sp:
                 loss, grads = jax.value_and_grad(loss_of)(params, batch)
                 sp.sync(loss)
@@ -598,43 +601,45 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
                     axis=comp_axes, nworkers=comp_n,
                     fuse_encode=fuse_encode, **kw)
             else:
-                g_flat = (flat_of_chunks() if grads is None
-                          else pack_segs(grads))
+                with jax.named_scope("encode"):   # the gradient pack
+                    g_flat = (flat_of_chunks() if grads is None
+                              else pack_segs(grads))
                 if isinstance(compressor, comp.BucketedCompressor):
                     upd, ef_new, _ = exchange_bucketed(
                         compressor, ef32, g_flat, axis=comp_axes,
                         nworkers=comp_n, overlap=overlap, **kw)
-                else:
-                    with tr.span("exchange", cat="comm") as sp:
-                        upd, ef_new, _ = compressor.step(
-                            ef32, g_flat, axis=comp_axes, nworkers=comp_n,
-                            **kw)
-                        sp.sync(upd)
+                else:   # the compressor names its own stages
+                    upd, ef_new, _ = compressor.step(
+                        ef32, g_flat, axis=comp_axes, nworkers=comp_n,
+                        **kw)
             ef_new = jax.tree_util.tree_map(
                 lambda new, old: new.astype(old.dtype), ef_new, ef)
         else:
-            g_flat = flat_of_chunks() if grads is None else pack_segs(grads)
-            if comp_axes:                  # dense baseline over dp axes
-                upd = jax.lax.psum(g_flat, comp_axes)
-            else:                          # fsdp single-pod: nothing left
-                upd = g_flat               # already summed over 'data'
+            with jax.named_scope("encode"):   # the gradient pack alone
+                g_flat = (flat_of_chunks() if grads is None
+                          else pack_segs(grads))
+            with obtrace.phase("comm", "allreduce") as sp:
+                if comp_axes:              # dense baseline over dp axes
+                    upd = jax.lax.psum(g_flat, comp_axes)
+                else:                      # fsdp single-pod: nothing left
+                    upd = g_flat           # already summed over 'data'
+                sp.sync(upd)
             ef_new = ef
 
-        g_mean = upd / ma.dp_size
-
-        gsq = jnp.sum(g_mean * g_mean)
-        # coords are disjoint across 'model' (and across 'data' in fsdp)
-        norm_axes = tuple(a for a in (
-            ma.tp_axis, ma.data_axis if dp_mode == "fsdp" else None) if a)
-        if norm_axes:
-            gsq = jax.lax.psum(gsq, norm_axes)
-        gnorm = jnp.sqrt(gsq)
-        if clip_norm is not None:  # global-norm clip on the aggregated grad
-            g_mean = g_mean * jnp.minimum(1.0, clip_norm
-                                          / jnp.maximum(gnorm, 1e-12))
-        g_segs = unpack_segs(g_mean, params)
-
-        with tr.span("optimizer", cat="optimizer") as sp:
+        with obtrace.phase("optimizer") as sp:
+            g_mean = upd / ma.dp_size
+            gsq = jnp.sum(g_mean * g_mean)
+            # coords are disjoint across 'model' (and across 'data' in fsdp)
+            norm_axes = tuple(a for a in (
+                ma.tp_axis, ma.data_axis if dp_mode == "fsdp" else None)
+                if a)
+            if norm_axes:
+                gsq = jax.lax.psum(gsq, norm_axes)
+            gnorm = jnp.sqrt(gsq)
+            if clip_norm is not None:  # global-norm clip, aggregated grad
+                g_mean = g_mean * jnp.minimum(1.0, clip_norm
+                                              / jnp.maximum(gnorm, 1e-12))
+            g_segs = unpack_segs(g_mean, params)
             new_params, new_opt = {}, {}
             for k in SEG_NAMES:
                 new_params[k], new_opt[k] = opt.apply(params[k], g_segs[k],

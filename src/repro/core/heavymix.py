@@ -47,23 +47,28 @@ def heavymix(cfg: cs.SketchConfig, sketch: Array, k: int, d: int, *,
     per-chunk winners — mathematically identical to a flat top-k (every
     global winner wins its chunk), but the (d,)-sized estimate/score
     vectors never materialize (they are multi-GB at d ~ 10^9).
+
+    The estimates run under the device scope ``recover/decode``, the
+    threshold, scores and top-k under ``recover/select``.
     """
     if estimates is None and not faithful and d > _CHUNK and d > 4 * k:
         return _heavymix_chunked(cfg, sketch, k, d)
-    est = cs.decode(cfg, sketch, d) if estimates is None else estimates
-    l2sq = cs.l2sq_estimate(sketch)
-    heavy = est * est >= l2sq / k  # (alpha, l2)-heavy coordinates
+    with jax.named_scope("recover/decode"):
+        est = cs.decode(cfg, sketch, d) if estimates is None else estimates
+    with jax.named_scope("recover/select"):
+        l2sq = cs.l2sq_estimate(sketch)
+        heavy = est * est >= l2sq / k  # (alpha, l2)-heavy coordinates
 
-    if faithful:
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        filler = jax.random.uniform(key, (d,))  # random priority for NH
-        score = jnp.where(heavy, jnp.abs(est) + _BIG, filler)
-    else:
-        score = jnp.where(heavy, jnp.abs(est) + _BIG, jnp.abs(est))
+        if faithful:
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            filler = jax.random.uniform(key, (d,))  # random priority for NH
+            score = jnp.where(heavy, jnp.abs(est) + _BIG, filler)
+        else:
+            score = jnp.where(heavy, jnp.abs(est) + _BIG, jnp.abs(est))
 
-    _, idx = jax.lax.top_k(score, k)
-    return idx, est[idx]
+        _, idx = jax.lax.top_k(score, k)
+        return idx, est[idx]
 
 
 def _heavymix_chunked(cfg: cs.SketchConfig, sketch: Array, k: int,
@@ -74,21 +79,26 @@ def _heavymix_chunked(cfg: cs.SketchConfig, sketch: Array, k: int,
     top-|H| by |estimate| (heaviness is a threshold on est^2), so a plain
     top-k by |est| selects H ∪ greedy fill — no heavy-boost term needed.
     """
-    sk = sketch.astype(jnp.float32)
+    with jax.named_scope("recover/decode"):
+        sk = sketch.astype(jnp.float32)
     n = (d + _CHUNK - 1) // _CHUNK
     k_c = min(k, _CHUNK)
 
     def body(_, i):
-        base = i * _CHUNK
-        idx = jnp.arange(_CHUNK) + base
-        buckets, signs = cs.hash_buckets(cfg, idx)
-        est = jnp.median(jnp.take_along_axis(sk, buckets, axis=1) * signs,
-                         axis=0)
-        score = jnp.where(idx < d, jnp.abs(est), -1.0)  # mask tail padding
-        v, loc = jax.lax.top_k(score, k_c)
-        return None, (v, loc + base, est[loc])
+        with jax.named_scope("recover/decode"):
+            base = i * _CHUNK
+            idx = jnp.arange(_CHUNK) + base
+            buckets, signs = cs.hash_buckets(cfg, idx)
+            est = jnp.median(
+                jnp.take_along_axis(sk, buckets, axis=1) * signs, axis=0)
+        with jax.named_scope("recover/select"):
+            score = jnp.where(idx < d, jnp.abs(est), -1.0)  # tail padding
+            v, loc = jax.lax.top_k(score, k_c)
+            return None, (v, loc + base, est[loc])
 
     _, (vals, idxs, ests) = jax.lax.scan(body, None, jnp.arange(n))
-    vals, idxs, ests = vals.reshape(-1), idxs.reshape(-1), ests.reshape(-1)
-    _, sel = jax.lax.top_k(vals, k)
-    return idxs[sel], ests[sel]
+    with jax.named_scope("recover/select"):
+        vals, idxs, ests = (vals.reshape(-1), idxs.reshape(-1),
+                            ests.reshape(-1))
+        _, sel = jax.lax.top_k(vals, k)
+        return idxs[sel], ests[sel]

@@ -135,13 +135,18 @@ def init_state(spec: RunSpec, cfg, opt, ts):
 
 
 def worker_batch(stream: LMStream, step: int, spec: RunSpec) -> dict:
-    """The global batch of ``step``, split into P worker rows when P > 1."""
-    gb = stream.global_batch_at(step)
+    """The global batch of ``step``, split into P worker rows when P > 1.
+    Host spans ``stream`` and ``reshape`` (cat ``input``) under an active
+    ``obs`` tracer."""
+    tr = obs.trace.current()
+    with tr.span("stream", cat="input"):
+        gb = stream.global_batch_at(step)
     P = spec.cluster.p
     if P == 1:
         return gb
-    return jax.tree_util.tree_map(
-        lambda a: a.reshape((P, spec.batch // P) + a.shape[1:]), gb)
+    with tr.span("reshape", cat="input"):
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape((P, spec.batch // P) + a.shape[1:]), gb)
 
 
 def resolve_spec(args) -> RunSpec:
@@ -322,6 +327,9 @@ def main(argv=None) -> dict:
     tnull = tracer if tracer is not None else obs.NULL
     prov = obs.provenance(spec) if (spec.trace or args.json) else None
     met = obs.Metrics() if args.json else None
+    # backend compiles by function over the run (trace@2 ``compiles/*``)
+    compiles_at = (obs.compile_counts().snapshot()["counters"]
+                   if args.json else None)
     probe_at = None
     if tracer is not None:
         # probe AFTER the warmup step when the run is long enough, so the
@@ -361,6 +369,9 @@ def main(argv=None) -> dict:
             for i, s in enumerate(per):
                 met.counter(f"bytes_wire/b{i}").inc(
                     s.bytes_out * P * len(records))
+        for name, n in obs.compile_counts().snapshot()["counters"].items():
+            if n > compiles_at.get(name, 0):
+                met.counter(name).inc(n - compiles_at.get(name, 0))
         err = _recovery_probe(ts, spec.seed)
         if err is not None:
             met.gauge("recovery_error_probe").set(err)

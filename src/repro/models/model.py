@@ -306,29 +306,38 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
     # and the gather transpose (psum_scatter under tp/fsdp sharding) runs a
     # single time in top_grads — a K-chunk step must not multiply the
     # top-segment collectives by K+2.
-    (g_ts, g_tr), vjp_gather = jax.vjp(lambda a, b: (gs_(a), gr_(b)), ts, tr)
+    # Every stage differentiated below runs under the device scope
+    # ``forward``; its VJP's ops then carry ``transpose(jvp(forward))``.
+    def gather(a, b):
+        with jax.named_scope("forward"):
+            return gs_(a), gr_(b)
+
+    (g_ts, g_tr), vjp_gather = jax.vjp(gather, ts, tr)
 
     def prologue(ts, tr):
-        top = fs.top_params(ts, tr, ctx.dtype)
-        return embed_lookup(top["embed"], tokens, ctx), jnp.float32(0.0)
+        with jax.named_scope("forward"):
+            top = fs.top_params(ts, tr, ctx.dtype)
+            return embed_lookup(top["embed"], tokens, ctx), jnp.float32(0.0)
 
     def chunk_fn(carry, vs, vr, ts, tr):
-        top = fs.top_params(ts, tr, ctx.dtype)
-        body = _cycle_scan_body(cfg, ctx, fs, top.get("shared_attn"), pos,
-                                "train", cross_kv, None, gs_, gr_)
-        if remat:
-            body = jax.checkpoint(body)
+        with jax.named_scope("forward"):
+            top = fs.top_params(ts, tr, ctx.dtype)
+            body = _cycle_scan_body(cfg, ctx, fs, top.get("shared_attn"),
+                                    pos, "train", cross_kv, None, gs_, gr_)
+            if remat:
+                body = jax.checkpoint(body)
 
-        def cyc(c, v):
-            return body(c, (v[0], v[1], None))
+            def cyc(c, v):
+                return body(c, (v[0], v[1], None))
 
-        return _scan_cycles(cyc, carry, vs, vr, remat)
+            return _scan_cycles(cyc, carry, vs, vr, remat)
 
     def epilogue(carry, ts, tr):
-        x, aux = carry
-        top = fs.top_params(ts, tr, ctx.dtype)
-        x = rmsnorm(x, top["final_norm"], cfg.norm_eps)
-        return _loss_head(cfg, ctx, x, aux, top, batch["labels"])
+        with jax.named_scope("forward"):
+            x, aux = carry
+            top = fs.top_params(ts, tr, ctx.dtype)
+            x = rmsnorm(x, top["final_norm"], cfg.norm_eps)
+            return _loss_head(cfg, ctx, x, aux, top, batch["labels"])
 
     carry, vjp_pro = jax.vjp(prologue, g_ts, g_tr)
     chunk_vjps = []

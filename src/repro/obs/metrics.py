@@ -106,6 +106,36 @@ class Metrics:
 
 
 # ---------------------------------------------------------------------------
+# Compiles by function
+# ---------------------------------------------------------------------------
+
+# JAX's monitoring event around each XLA backend compile (a persistent
+# compile-cache load included), with the compiled function's ``fun_name``.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_COMPILES: Metrics | None = None
+
+
+def compile_counts() -> Metrics:
+    """The process's backend compiles, one counter per compiled function
+    (``compiles/<fun_name>``). The first call installs the
+    ``jax.monitoring`` listener; every call returns the same registry, so
+    a caller diffs two ``snapshot()``s to count a stretch of its run."""
+    global _COMPILES
+    if _COMPILES is None:
+        import jax
+        reg = Metrics()
+
+        def on_event(event: str, duration: float, **kw) -> None:
+            if event == COMPILE_EVENT:
+                reg.counter(f"compiles/{kw.get('fun_name', '?')}").inc()
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        _COMPILES = reg
+    return _COMPILES
+
+
+# ---------------------------------------------------------------------------
 # trace@2 document
 # ---------------------------------------------------------------------------
 

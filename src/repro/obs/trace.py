@@ -21,10 +21,19 @@ per-phase device time. Inside ``jax.jit`` spans cannot observe anything
 runs one un-jitted PROBE step for phase attribution and wraps the jitted
 steps in driver-level spans (see launch/train.py).
 
+Under ``jit`` the phases are named on the device instead: ``phase(cat)``
+enters ``jax.named_scope(cat)`` together with the ambient span, so every
+op the phase emits carries the category in its HLO ``op_name`` metadata,
+which the profiler's device trace reports per op. Scopes are always on:
+they are metadata and change no op. A live span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so while a profiler session
+captures, the program's host spans sit on the device trace's clock.
+
 Span taxonomy — the ``cat`` field; the audit and the sim export share it:
 
     step       one whole training step (driver / sim timeline)
     probe      the eager instrumented step the phase spans live under
+    input      host batch build (stream generation, worker reshape)
     forward    forward pass (chunked path; monolithic fwd+bwd = backward)
     backward   backward chunk VJPs / monolithic value_and_grad
     encode     per-bucket sketch encode (+ readiness instants)
@@ -33,6 +42,21 @@ Span taxonomy — the ``cat`` field; the audit and the sim export share it:
     optimizer  the segment-wise optimizer sweep
     runtime    heartbeat/elastic/straggler instants
     stall      sim-only: barrier + detection waits
+
+Device scopes of the train step (``SCOPES``), the op-level names of the
+same taxonomy:
+
+    forward               the model's forward pass (inside autodiff)
+    backward              never entered: ops whose name stack holds
+                          ``transpose(...forward...)``; remat recompute
+                          lands here (``.../checkpoint/rematted_computation``)
+    encode                gradient pack, EF add, Count-Sketch encode, cast
+    comm                  sketch merge (psum or tree) / dense psum
+    recover/decode        HEAVYMIX estimates: hash, gather, median
+    recover/select        heavy threshold, scores, top-k
+    recover/second_round  exact values of Top_k (gather, psum, scatter)
+                          and the EF residual
+    optimizer             mean, norm, clip, unpack, optimizer update
 
 ``from_sim(result)`` renders a ``sim.cluster.SimResult`` into the same
 schema, so a measured trace and a simulated one for the same RunSpec are
@@ -51,6 +75,10 @@ TRACE_SCHEMA = "repro.obs/trace@1"
 # Phase categories shared by the train probe, the sim export, and
 # benchmarks/overlap_audit.py.
 PHASES = ("forward", "backward", "encode", "comm", "recover")
+
+# Device scopes of the train step (module docstring).
+SCOPES = ("forward", "backward", "encode", "comm", "recover/decode",
+          "recover/select", "recover/second_round", "optimizer")
 
 
 # ---------------------------------------------------------------------------
@@ -103,6 +131,17 @@ def current() -> "Tracer | _NullTracer":
     return _CURRENT if _CURRENT is not None else NULL
 
 
+@contextlib.contextmanager
+def phase(cat: str, name: str | None = None):
+    """Enter one phase of the step: ``jax.named_scope(cat)`` for the ops
+    traced inside (their HLO ``op_name``, read from the device trace) and
+    the ambient tracer's span ``name`` (default ``cat``) of category
+    ``cat``, which fires only in an eager step. Yields the span."""
+    import jax
+    with jax.named_scope(cat), current().span(name or cat, cat=cat) as sp:
+        yield sp
+
+
 # ---------------------------------------------------------------------------
 # Tracer
 # ---------------------------------------------------------------------------
@@ -111,7 +150,7 @@ def current() -> "Tracer | _NullTracer":
 class Span:
     """One open span; close with ``tracer.end(span)`` or the with-block."""
 
-    __slots__ = ("_tr", "name", "cat", "track", "args", "t0")
+    __slots__ = ("_tr", "name", "cat", "track", "args", "t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, track: str,
                  args: dict | None):
@@ -120,6 +159,10 @@ class Span:
         self.cat = cat
         self.track = track
         self.args = args
+        # the same span on the profiler's clock; records only while a
+        # profiler session captures
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(name)
 
     def sync(self, x):
         """Block until ``x``'s arrays are computed, then return it.
@@ -164,6 +207,7 @@ class Tracer:
     def begin(self, name: str, *, cat: str = "", track: str = "main",
               args: dict | None = None) -> Span:
         sp = Span(self, name, cat, track, args)
+        sp._ann.__enter__()
         sp.t0 = self._clock() - self.epoch
         self._stacks.setdefault(track, []).append(sp)
         return sp
@@ -177,6 +221,7 @@ class Tracer:
                 f"span end out of order on track {span.track!r}: closing "
                 f"{span.name!r} but the open stack is {open_names}")
         stack.pop()
+        span._ann.__exit__(None, None, None)
         self.events.append({"ph": "X", "name": span.name, "cat": span.cat,
                             "track": span.track, "ts": span.t0,
                             "dur": t1 - span.t0, "args": span.args})

@@ -132,6 +132,101 @@ def test_save_load_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# Device scopes, the profiler's clock, compiles by function
+# ---------------------------------------------------------------------------
+
+
+def test_phase_names_the_ops_and_opens_the_span():
+    import jax
+    import jax.numpy as jnp
+
+    def f(x):
+        with obtrace.phase("encode", "encode/b0") as sp:
+            return sp.sync(jnp.sin(x) * 2)
+
+    text = jax.jit(f).lower(jnp.ones(8)).as_text(debug_info=True)
+    assert "/encode/sin" in text and "/encode/mul" in text
+    clk = FakeClock()
+    tr = obs.Tracer(clock=clk, epoch=0.0)
+    with tr.activate():
+        f(jnp.ones(8))               # eager: the span fires
+    (e,) = tr.events
+    assert (e["name"], e["cat"]) == ("encode/b0", "encode")
+    f(jnp.ones(8))                   # NULL tracer: no span anywhere
+    assert len(tr.events) == 1
+
+
+def test_spans_land_on_the_profiler_clock(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = obs.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs-outer", cat="input"):
+            with tr.span("obs-inner", cat="input"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {e.name for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events}
+    assert {"obs-outer", "obs-inner"} <= host
+    assert obtrace.validate(tr) == 2
+
+
+def test_compile_counts_by_function():
+    import jax
+    import jax.numpy as jnp
+    reg = obs.compile_counts()
+    assert obs.compile_counts() is reg       # one listener per process
+
+    def obs_probe_fn(x):
+        return x * 3
+
+    before = reg.snapshot()["counters"].get("compiles/jit(obs_probe_fn)", 0)
+    f = jax.jit(obs_probe_fn)
+    f(jnp.ones(3))
+    f(jnp.ones(3))                   # cached: no second compile
+    f(jnp.ones(4))                   # a new shape compiles again
+    after = reg.snapshot()["counters"]["compiles/jit(obs_probe_fn)"]
+    assert after - before == 2
+
+
+def test_stream_compiles_its_scan_every_step():
+    # the eager lax.scan over a fresh closure in LMStream._tokens compiles
+    # one program each call: the harness's compiles_per_step of 1.0
+    from repro.data import LMStream
+    reg = obs.compile_counts()
+    s = LMStream(vocab_size=64, seq_len=8, global_batch=2)
+    s.global_batch_at(0)
+    before = reg.snapshot()["counters"]
+    for step in (1, 2, 3):
+        s.global_batch_at(step)
+    after = reg.snapshot()["counters"]
+    grew = {k: v - before.get(k, 0) for k, v in after.items()
+            if v > before.get(k, 0)}
+    assert grew == {"compiles/jit(scan)": 3}
+
+
+def test_worker_batch_spans_the_stream_and_the_reshape():
+    from repro.data import LMStream
+    from repro.launch import train
+    spec = RunSpec(smoke=True, batch=4, seq=8)
+    spec = dataclasses.replace(
+        spec, cluster=dataclasses.replace(spec.cluster, p=2))
+    tr = obs.Tracer()
+    with tr.activate():
+        b = train.worker_batch(LMStream(vocab_size=64, seq_len=8,
+                                        global_batch=4), 0, spec)
+    assert b["tokens"].shape == (2, 2, 8)
+    assert [(e["name"], e["cat"]) for e in tr.events] == [
+        ("stream", "input"), ("reshape", "input")]
+
+
+# ---------------------------------------------------------------------------
 # Metrics + trace@2
 # ---------------------------------------------------------------------------
 
@@ -330,6 +425,14 @@ def test_train_trace_has_probe_phases_and_step_spans(train_runs):
     assert len(obtrace.bucket_durations(doc, "comm", "allreduce/b")) == 2
     assert len(obtrace.bucket_durations(doc, "recover", "recover/b")) == 2
     assert obtrace.instants(doc, "ready/b0")
+
+
+def test_train_trace2_counts_compiles_by_function(train_runs):
+    _, _, _, json_p = train_runs
+    with open(json_p) as f:
+        counters = json.load(f)["metrics"]["counters"]
+    assert counters["compiles/jit(train_step)"] == 1   # one step program
+    assert counters["compiles/jit(scan)"] >= STEPS     # LMStream, per step
 
 
 def test_train_trace2_superset_roundtrips_through_calibrate(train_runs):
