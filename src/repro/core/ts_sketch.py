@@ -1,7 +1,7 @@
 """TS-sketch: a TPU-native O(d·R) Count-Sketch variant (beyond-paper).
 
 The exact multiply-shift Count-Sketch needs either scatter-add (no TPU
-atomics, slow lowering) or the one-hot-matmul kernel (exact, but 2·d·W·R
+atomics, slow lowering) or the one-hot-matmul kernel (exact, but 3·d·W·R
 MACs — the price quantified in EXPERIMENTS.md §Roofline). This variant
 keeps the multiply-shift SIGN hash per coordinate but replaces the bucket
 hash with a per-row *digit transpose*:

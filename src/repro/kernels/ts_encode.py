@@ -15,7 +15,7 @@ reduces with
   4. rotate by c (jnp.roll) and accumulate into the (R, W) VMEM tile.
 
 Pure vector ops — no gather/scatter/matmul. VMEM ~ (R+3)*W*4 B. Compare
-kernels/sketch_encode.py (exact hash): 2*d*W*R MXU MACs vs ~4*d*R VPU ops.
+kernels/sketch_encode.py (exact hash): 3*d*W*R MXU MACs vs ~4*d*R VPU ops.
 
 Oracle: repro.core.ts_sketch.encode (tests/test_ts_sketch.py sweeps,
 interpret=True).

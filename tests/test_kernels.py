@@ -19,11 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import count_sketch as cs
 from repro.core.count_sketch import SketchConfig
 from repro.kernels import ops, ref
 from repro.kernels.dispatch import default_interpret, resolve_dispatch
 from repro.kernels.sketch_decode import sketch_decode
-from repro.kernels.sketch_encode import sketch_encode, sketch_encode_bucketed
+from repro.kernels.sketch_encode import (hi_block, sketch_encode,
+                                          sketch_encode_bucketed)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -51,10 +53,12 @@ def test_encode_dtypes(dtype):
                                rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("block_d,block_w", [(256, 128), (1024, 512),
-                                             (4096, 1024)])
+@pytest.mark.parametrize("block_d,block_w", [(256, 2048), (1024, 4096),
+                                             (4096, 8192)])
 def test_encode_block_shapes(block_d, block_w):
-    cfg = SketchConfig(rows=3, width=1024, seed=5)
+    """Element blocks of 256 to 4096 and one to four passes over ``g``
+    (``block_w`` buckets a pass: 16, 32 and 64 of the 64 hi rows)."""
+    cfg = SketchConfig(rows=3, width=8192, seed=5)
     g = jax.random.normal(jax.random.PRNGKey(1), (8192,))
     out = sketch_encode(cfg, g, block_d=block_d, block_w=block_w,
                         interpret=True)
@@ -63,12 +67,13 @@ def test_encode_block_shapes(block_d, block_w):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("width,block_w", [(512, 384), (1024, 384),
-                                           (2048, 768)])
+@pytest.mark.parametrize("width,block_w", [(8192, 6144), (16384, 6144),
+                                           (16384, 10240)])
 def test_encode_width_not_divisible_by_block(width, block_w):
-    """Regression: n_w = width // block_w silently DROPPED the tail column
-    blocks for any width not a block_w multiple — every coordinate hashed
-    into the dropped buckets vanished from the sketch."""
+    """Regression: a grid of width // block_w passes silently DROPPED the
+    tail buckets for any width not a block_w multiple — every coordinate
+    hashed into the dropped buckets vanished from the sketch. The hi rows
+    pad up to whole passes instead."""
     cfg = SketchConfig(rows=4, width=width, seed=9)
     g = jax.random.normal(jax.random.PRNGKey(7), (6000,))
     out = sketch_encode(cfg, g, block_w=block_w, interpret=True)
@@ -79,6 +84,79 @@ def test_encode_width_not_divisible_by_block(width, block_w):
     # the tail columns specifically must carry mass, not zeros
     tail = np.asarray(want)[:, (width // block_w) * block_w:]
     assert np.abs(tail).max() > 0
+
+
+def factored_encode(cfg: SketchConfig, g, offset: int = 0):
+    """The kernel's factored contraction in plain jnp: bucket = hi * 128 +
+    lo, the three bf16 parts of the signed values one-hot at hi, contracted
+    over the elements against a 0/1 one-hot at lo."""
+    g = g.reshape(-1).astype(jnp.float32)
+    buckets, signs = cs.hash_buckets(cfg, jnp.arange(g.shape[0]) + offset)
+    n_hi = -(-cfg.width // 128)
+    v = signs * g  # (R, d)
+    hi = v.astype(jnp.bfloat16)
+    mid = (v - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    lo = (v - hi.astype(jnp.float32) - mid.astype(jnp.float32))
+    at_hi = jax.nn.one_hot(buckets >> 7, n_hi, dtype=jnp.bfloat16)
+    at_lo = jax.nn.one_hot(buckets & 127, 128, dtype=jnp.bfloat16)
+    out = sum(jnp.einsum("rdh,rdl->rhl",
+                         at_hi * p.astype(jnp.bfloat16)[..., None], at_lo,
+                         preferred_element_type=jnp.float32)
+              for p in (hi, mid, lo))
+    return out.reshape(cfg.rows, n_hi * 128)[:, :cfg.width]
+
+
+@pytest.mark.parametrize("width", [64, 128, 1024])
+def test_factored_formulation_equals_onehot(width):
+    """The factorisation itself, without Pallas: the (hi one-hot x signed
+    values) (lo one-hot) contraction equals the (d x W) one-hot matmul."""
+    cfg = SketchConfig(rows=3, width=width, seed=4)
+    g = jax.random.normal(jax.random.PRNGKey(width), (3000,))
+    np.testing.assert_allclose(
+        np.asarray(factored_encode(cfg, g)),
+        np.asarray(ref.count_sketch_encode_onehot(cfg, g)),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("offset", [0, 12345])
+@pytest.mark.parametrize("width", [64, 128, 16384, 65536])
+def test_encode_factored_widths(width, offset):
+    """Under 128 buckets (one hi row, padded lanes), 128 (one hi row),
+    the benchmark's 16384 (128 x 128) and 65536 (four passes of 128 hi
+    rows at the default ``block_w``), at coordinate 0 and at an offset."""
+    cfg = SketchConfig(rows=5, width=width, seed=8)
+    g = jax.random.normal(jax.random.PRNGKey(width + offset), (5000,))
+    out = sketch_encode(cfg, g, index_offset=offset, interpret=True)
+    want = ref.count_sketch_encode(cfg, g, offset=offset)
+    assert out.shape == (5, width)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(factored_encode(cfg, g, offset)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_encode_vmap_two_workers():
+    """Two workers vmapped, as in the benchmark, at its rows and width."""
+    cfg = SketchConfig(rows=5, width=16384, seed=0)
+    g = jax.random.normal(jax.random.PRNGKey(3), (2, 6000))
+    out = jax.vmap(lambda x: sketch_encode(cfg, x, interpret=True))(g)
+    assert out.shape == (2, 5, 16384)
+    for w in range(2):
+        want = ref.count_sketch_encode(cfg, g[w])
+        np.testing.assert_allclose(np.asarray(out[w]), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("width,block_w,want", [
+    (64, 16384, (16, 16)),        # one hi row, padded to a tile
+    (16384, 16384, (128, 128)),   # one pass
+    (65536, 16384, (128, 512)),   # four passes
+    (16384, 1000, (16, 128)),     # under one tile: one tile a pass
+    (16384, 6144, (48, 144)),     # passes pad past the last hi row
+])
+def test_hi_block_geometry(width, block_w, want):
+    assert hi_block(width, block_w) == want
 
 
 @pytest.mark.parametrize("width,block_w", [(512, 384), (2048, 768)])

@@ -10,6 +10,7 @@ only the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,3 +70,18 @@ def test_kernel_compiles_for_v5e(one_chip, case):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+CELL_D = 75_503_616  # musicgen-large at one layer: the benchmark's flat size
+
+
+def test_encode_at_cell_size_is_one_named_kernel(one_chip):
+    """Two vmapped workers at the benchmark's flat size compile to exactly
+    one Mosaic kernel, and its instruction keeps the name ``sketch_encode``,
+    which the trace reader matches to time the encode."""
+    g = jax.ShapeDtypeStruct((2, CELL_D), jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.vmap(_encode)).lower(g).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, flags=re.M)
+    assert len(calls) == 1, calls
+    assert "sketch_encode" in calls[0], calls
