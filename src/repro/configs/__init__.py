@@ -1,7 +1,8 @@
 """Architecture registry: ``--arch <id>`` resolves here.
 
-``ARCHS``/``SMOKES`` map the ten assigned architecture ids to their exact
-published configs and to reduced same-family smoke configs. ``DP_MODE``
+``ARCHS``/``SMOKES`` map the architecture ids to their exact published
+configs (and kanana's one-chip expert share, ``-ep16``) and to reduced
+same-family smoke configs. ``DP_MODE``
 records the production data-axis policy per arch (see DESIGN.md §3.5/§8):
 
   'dp'   — parameters replicated over the data axis; gs-SGD compresses the
@@ -18,6 +19,9 @@ from __future__ import annotations
 from repro.configs import shapes
 from repro.configs.granite_moe_3b_a800m import CONFIG as _granite
 from repro.configs.granite_moe_3b_a800m import SMOKE as _granite_s
+from repro.configs.kanana_2_30b_a3b import CONFIG as _kanana
+from repro.configs.kanana_2_30b_a3b import EP16 as _kanana_ep16
+from repro.configs.kanana_2_30b_a3b import SMOKE as _kanana_s
 from repro.configs.llama_3_2_vision_11b import CONFIG as _llava
 from repro.configs.llama_3_2_vision_11b import SMOKE as _llava_s
 from repro.configs.minicpm_2b import CONFIG as _minicpm
@@ -49,6 +53,8 @@ ARCHS: dict[str, ArchConfig] = {
     "rwkv6-7b": _rwkv6,
     "musicgen-large": _musicgen,
     "zamba2-2.7b": _zamba2,
+    "kanana-2-30b-a3b": _kanana,
+    "kanana-2-30b-a3b-ep16": _kanana_ep16,
 }
 
 SMOKES: dict[str, ArchConfig] = {
@@ -62,6 +68,7 @@ SMOKES: dict[str, ArchConfig] = {
     "rwkv6-7b": _rwkv6_s,
     "musicgen-large": _musicgen_s,
     "zamba2-2.7b": _zamba2_s,
+    "kanana-2-30b-a3b": _kanana_s,
 }
 
 # Production data-axis policy (HBM-driven; see module docstring).
@@ -76,6 +83,8 @@ DP_MODE: dict[str, str] = {
     "rwkv6-7b": "fsdp",               # ~7.6B
     "musicgen-large": "dp",           # ~3.3B
     "zamba2-2.7b": "dp",              # ~2.7B
+    "kanana-2-30b-a3b": "fsdp",       # ~30.7B
+    "kanana-2-30b-a3b-ep16": "dp",    # one chip's share, ~0.43B at 5 layers
 }
 
 

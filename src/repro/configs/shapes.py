@@ -32,7 +32,10 @@ SHAPES: dict[str, ShapeCase] = {
 
 
 def applicable(cfg: ArchConfig, shape: str) -> bool:
-    """Is this (arch, shape) cell runnable? (long_500k: sub-quadratic only)"""
+    """Is this (arch, shape) cell runnable? (long_500k: sub-quadratic only;
+    latent attention is trained only: no serving cache)"""
+    if SHAPES[shape].kind != "train" and cfg.mla:
+        return False
     if shape == "long_500k":
         return cfg.family in ("ssm", "hybrid")
     return True
@@ -41,5 +44,7 @@ def applicable(cfg: ArchConfig, shape: str) -> bool:
 def skip_reason(cfg: ArchConfig, shape: str) -> str | None:
     if applicable(cfg, shape):
         return None
+    if cfg.mla:
+        return f"{cfg.name}: latent attention has no serving cache"
     return (f"{cfg.name} is pure full-attention; a 512k-token dense-attention "
             "decode is skipped per assignment rules (sub-quadratic archs only)")

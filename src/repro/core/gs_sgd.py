@@ -30,7 +30,6 @@ multi-worker simulations used in tests and convergence benches.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable
 
 import jax
@@ -38,11 +37,12 @@ import jax.numpy as jnp
 
 from repro.core import compression as comp
 from repro.obs import trace as obtrace
-from repro.models.common import ArchConfig, ShardCtx
+from repro.models.common import ArchConfig, ShardCtx, stored_experts
 from repro.models.flatten import (SEG_NAMES, BucketPlan, FlatSpec,
                                   bucket_plan, bucket_sizes, make_flat_spec,
                                   pack_segs, packed_offsets, unpack_segs)
 from repro.models import model as mdl
+from repro.models import moe as moe_lib
 from repro.optim.optimizers import Optimizer
 
 Array = jax.Array
@@ -376,18 +376,26 @@ class TrainStep:
                 "init_state builds single-device state only (tp=1, dp=1); "
                 f"got tp={self.ma.tp}, dp={self.ma.dp_size}")
         params = init_flat_params(self.fs.cfg, key, 1, self.fs)
-        return make_state(params, opt, self.compressor, self.d_local)
+        return make_state(params, opt, self.compressor, self.d_local,
+                          cfg=self.fs.cfg)
 
 
 def make_state(params: dict, opt: Optimizer, compressor, d_local: int,
-               ef_dtype=jnp.float32) -> dict:
+               ef_dtype=jnp.float32, cfg: ArchConfig | None = None) -> dict:
+    """Step-0 state. With ``cfg`` it also holds what the model keeps
+    outside its parameters: a sigmoid router's selection bias (out of the
+    gradient, the optimizer and the exchanged vector)."""
     opt_state = {k: opt.init(v.shape) for k, v in params.items()}
     ef = (compressor.init(d_local) if compressor is not None else
           jnp.zeros((0,), jnp.float32))
     if compressor is not None and ef_dtype != jnp.float32:
         ef = jax.tree_util.tree_map(lambda a: a.astype(ef_dtype), ef)
-    return {"params": params, "opt": opt_state, "ef": ef,
-            "step": jnp.int32(0)}
+    state = {"params": params, "opt": opt_state, "ef": ef,
+             "step": jnp.int32(0)}
+    bias = None if cfg is None else moe_lib.init_router_bias(cfg)
+    if bias is not None:
+        state["router_bias"] = bias
+    return state
 
 
 def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
@@ -480,8 +488,8 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
 
     # In 'dp' the compressor sums raw per-worker grads over all dp axes; in
     # 'fsdp' backward's psum_scatter has already summed over 'data', so only
-    # the pod axis remains. Either way ``upd`` ends up as the SUM over all
-    # dp_size workers and is divided once below.
+    # the pod axis remains. Either way ``g_sum`` ends up as the SUM over
+    # all dp_size workers and is divided once below.
     if dp_mode == "dp":
         comp_axes: tuple[str, ...] = ma.dp_axes
         comp_n = ma.dp_size
@@ -518,6 +526,8 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
                    include: Array | None = None) -> tuple[dict, dict]:
         params, opt_state, ef, step = (state["params"], state["opt"],
                                        state["ef"], state["step"])
+        bias = (state["router_bias"] if cfg.router == "sigmoid"  # its state
+                else None)
 
         # The loss is replicated across the TP axis, so each rank seeds a
         # cotangent of 1 and the collective transposes (psum -> psum,
@@ -530,8 +540,10 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
         def loss_of(p, b):
             # inside autodiff: the backward is this scope's transpose
             with jax.named_scope("forward"):
-                return inv_tp * mdl.loss_fn(cfg, ctx, fs, p, b,
-                                            gathers=gathers, remat=remat)
+                loss, load = mdl.loss_fn(cfg, ctx, fs, p, b, gathers=gathers,
+                                         remat=remat, router_bias=bias,
+                                         with_load=True)
+                return inv_tp * loss, load
 
         tr = obtrace.current()
         b_loc = batch["tokens"].shape[0]
@@ -541,9 +553,10 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
             # Chunked backward: per-chunk VJPs emit gradient slices in
             # reverse order (seeded with 1/tp, mirroring loss_of's scaling)
             with obtrace.phase("forward") as sp:
-                loss, bwd_steps, top_grads = mdl.chunked_loss_vjp(
+                loss, bwd_steps, top_grads, load = mdl.chunked_loss_vjp(
                     cfg, ctx, fs, params, batch, chunks=bwd_chunks,
-                    gathers=gathers, remat=remat, grad_seed=inv_tp)
+                    gathers=gathers, remat=remat, grad_seed=inv_tp,
+                    router_bias=bias)
                 sp.sync(loss)
             loss = inv_tp * loss
             grads = None
@@ -553,7 +566,8 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
             # enters no scope (loss_of names the forward, and the
             # backward is its transpose)
             with tr.span("loss_and_grad", cat="backward") as sp:
-                loss, grads = jax.value_and_grad(loss_of)(params, batch)
+                (loss, load), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params, batch)
                 sp.sync(loss)
         else:
             if b_loc % mb != 0:
@@ -566,14 +580,16 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
 
             def acc_body(carry, b):
                 l_acc, g_acc = carry
-                l, g = jax.value_and_grad(loss_of)(params, b)
+                (l, load), g = jax.value_and_grad(loss_of, has_aux=True)(
+                    params, b)
                 g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
-                return (l_acc + l, g_acc), None
+                return (l_acc + l, g_acc), load
 
             zeros = jax.tree_util.tree_map(
                 lambda a: jnp.zeros(a.shape, jnp.float32), params)
-            (loss, grads), _ = jax.lax.scan(
+            (loss, grads), loads = jax.lax.scan(
                 acc_body, (jnp.float32(0.0), zeros), slices)
+            load = None if loads is None else jnp.sum(loads, axis=0)
             loss = loss / n_mb
             grads = jax.tree_util.tree_map(lambda g: g / n_mb, grads)
 
@@ -614,21 +630,26 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
                         **kw)
             ef_new = jax.tree_util.tree_map(
                 lambda new, old: new.astype(old.dtype), ef_new, ef)
-        else:
+            g_sum = unpack_segs(upd, params)
+        elif comp_axes:                    # dense baseline over dp axes
             with jax.named_scope("encode"):   # the gradient pack alone
                 g_flat = (flat_of_chunks() if grads is None
                           else pack_segs(grads))
             with obtrace.phase("comm", "allreduce") as sp:
-                if comp_axes:              # dense baseline over dp axes
-                    upd = jax.lax.psum(g_flat, comp_axes)
-                else:                      # fsdp single-pod: nothing left
-                    upd = g_flat           # already summed over 'data'
+                upd = jax.lax.psum(g_flat, comp_axes)
                 sp.sync(upd)
             ef_new = ef
+            g_sum = unpack_segs(upd, params)
+        else:   # one worker, or fsdp single-pod (summed over 'data'):
+            # nothing to exchange, so no flat copy of the gradient
+            g_sum = (unpack_segs(flat_of_chunks(), params) if grads is None
+                     else grads)
+            ef_new = ef
 
+        # g_sum: the gradient summed over the dp_size workers, by segment
         with obtrace.phase("optimizer") as sp:
-            g_mean = upd / ma.dp_size
-            gsq = jnp.sum(g_mean * g_mean)
+            g_mean = {k: g / ma.dp_size for k, g in g_sum.items()}
+            gsq = sum(jnp.sum(g * g) for g in g_mean.values())
             # coords are disjoint across 'model' (and across 'data' in fsdp)
             norm_axes = tuple(a for a in (
                 ma.tp_axis, ma.data_axis if dp_mode == "fsdp" else None)
@@ -637,12 +658,12 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
                 gsq = jax.lax.psum(gsq, norm_axes)
             gnorm = jnp.sqrt(gsq)
             if clip_norm is not None:  # global-norm clip, aggregated grad
-                g_mean = g_mean * jnp.minimum(1.0, clip_norm
-                                              / jnp.maximum(gnorm, 1e-12))
-            g_segs = unpack_segs(g_mean, params)
+                scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm,
+                                                                 1e-12))
+                g_mean = {k: g * scale for k, g in g_mean.items()}
             new_params, new_opt = {}, {}
             for k in SEG_NAMES:
-                new_params[k], new_opt[k] = opt.apply(params[k], g_segs[k],
+                new_params[k], new_opt[k] = opt.apply(params[k], g_mean[k],
                                                       opt_state[k], step)
             sp.sync(new_params["top_s"])
 
@@ -650,7 +671,13 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
         loss_rep = jax.lax.pmean(loss, ma.dp_axes) if ma.dp_axes else loss
         new_state = {"params": new_params, "opt": new_opt, "ef": ef_new,
                      "step": step + 1}
-        return new_state, {"loss": loss_rep, "grad_norm": gnorm}
+        metrics = {"loss": loss_rep, "grad_norm": gnorm}
+        if load is not None:
+            metrics.update(moe_counters(cfg, ctx, load))
+        if bias is not None:
+            total = jax.lax.psum(load, ma.dp_axes) if ma.dp_axes else load
+            new_state["router_bias"] = moe_lib.update_bias(cfg, bias, total)
+        return new_state, metrics
 
     return TrainStep(fn=train_step, fs=fs, ma=ma, dp_mode=dp_mode,
                      compressor=compressor, d_local=d_local,
@@ -659,6 +686,20 @@ def make_train_step(cfg: ArchConfig, ma: MeshAxes, opt: Optimizer, *,
                                               comp.BucketedCompressor) else 1),
                      overlap=overlap, bwd_chunks=(bwd_chunks or 0),
                      plan=plan, fuse_encode=fuse_encode)
+
+
+def moe_counters(cfg: ArchConfig, ctx: ShardCtx, load: Array) -> dict:
+    """This worker's step counters of its held experts, from the tokens
+    each MoE cycle routed to each expert (n_cycles, E): the rows routed
+    to held experts, over every cycle, and the largest held expert's
+    load over the mean held load of its cycle."""
+    with jax.named_scope("moe_route"):
+        n = stored_experts(cfg, ctx.tp) // ctx.tp
+        first = ctx.tp_rank() * n
+        held = jax.lax.dynamic_slice_in_dim(load, first, n, axis=1)
+        mean = jnp.maximum(jnp.mean(held, axis=1), 1e-9)
+        return {"moe_held_rows": jnp.sum(held),
+                "moe_held_max_over_mean": jnp.max(jnp.max(held, 1) / mean)}
 
 
 # ---------------------------------------------------------------------------

@@ -117,7 +117,7 @@ def init_state(spec: RunSpec, cfg, opt, ts):
     device receives only its own worker's block, so no device ever holds
     the P stacked copies."""
     params = init_flat_params(cfg, jax.random.PRNGKey(spec.seed), 1, ts.fs)
-    state = make_state(params, opt, ts.compressor, ts.d_local)
+    state = make_state(params, opt, ts.compressor, ts.d_local, cfg=cfg)
     P = spec.cluster.p
     if P > 1 and workers_on_mesh(P):
         sharded = worker_sharding(P)

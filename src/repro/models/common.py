@@ -48,10 +48,24 @@ class ArchConfig:
     head_dim: int = 0                # 0 -> d_model // n_heads
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # --- latent attention (MLA, DeepSeek-V2 §2.1; kv_lora_rank > 0) ---
+    kv_lora_rank: int = 0            # width of the compressed KV latent
+    qk_nope_dim: int = 0             # per-head query/key part without rotary
+    qk_rope_dim: int = 0             # per-head rotary part (key shared)
+    v_head_dim: int = 0              # rotary on pairs (2i, 2i+1)
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # routed experts the router scores
     experts_per_tok: int = 0
-    capacity_factor: float = 1.25
+    moe_d_ff: int = 0                # expert width; 0 -> d_ff
+    n_shared_experts: int = 0        # one SwiGLU of n_shared * expert width
+    router: str = "softmax"          # softmax (+ aux loss) | sigmoid
+    #   sigmoid: DeepSeek-V3 noaux_tc — top-k of score + a selection-only
+    #   bias (state, moved by bias_rate * sign(mean load - load) a step)
+    routed_scale: float = 1.0        # multiplies the normalised gates
+    bias_rate: float = 0.0
+    experts_held: int = 0            # experts stored here (this chip's
+    #   share of an expert-parallel layer); 0 -> all, split over 'model'
+    first_dense: int = 0             # leading dense layers before the cycles
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_head_dim: int = 64           # per-head channel dim for rwkv/mamba
@@ -73,6 +87,14 @@ class ArchConfig:
 
     # ---- cycle structure: what one scanned step applies ------------------
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
     def cycle(self) -> tuple[str, ...]:
         if self.family == "vlm" and self.cross_attn_every:
             return ("attn",) * (self.cross_attn_every - 1) + ("cross",)
@@ -85,7 +107,7 @@ class ArchConfig:
         per = len([b for b in self.cycle if b not in ("shared_attn",)])
         if self.family == "hybrid" and self.shared_attn_every:
             per = self.shared_attn_every
-        n, r = divmod(self.n_layers, per)
+        n, r = divmod(self.n_layers - self.first_dense, per)
         if r:
             raise ValueError(f"{self.name}: n_layers={self.n_layers} not a "
                              f"multiple of cycle length {per}")
@@ -116,7 +138,7 @@ class ArchConfig:
 
             ex = expert_leaves(specs["layers"])
             ex_total = sum(math.prod(s.shape) for s in ex)
-            n_exp = pad_to(self.n_experts, max(1, tp))
+            n_exp = stored_experts(self, tp)
             total = total - ex_total + int(ex_total * self.experts_per_tok / n_exp)
         return total
 
@@ -195,7 +217,14 @@ def padded_vocab(cfg: ArchConfig, tp: int) -> int:
 
 
 def padded_experts(cfg: ArchConfig, tp: int) -> int:
+    """Experts the router scores (padded to a multiple of tp, masked)."""
     return pad_to(cfg.n_experts, tp) if cfg.n_experts else 0
+
+
+def stored_experts(cfg: ArchConfig, tp: int) -> int:
+    """Experts whose weights this chip stores: its held share, or every
+    (padded) expert, split over 'model'."""
+    return cfg.experts_held or padded_experts(cfg, tp)
 
 
 # ---------------------------------------------------------------------------
@@ -239,6 +268,27 @@ def _attn_specs(cfg: ArchConfig, tp: int, cross: bool = False) -> dict:
     return s
 
 
+def _mla_specs(cfg: ArchConfig, tp: int) -> dict:
+    """Latent attention: per-head projections column-parallel by head; the
+    shared down-projection to [c_kv, k_pe] and its norm replicated."""
+    d, h = cfg.d_model, pad_to(cfg.n_heads, tp)
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    return {
+        "wq": Spec((d, h * qk), P(None, "model")),
+        "wkva": Spec((d, cfg.kv_lora_rank + cfg.qk_rope_dim), P(None, None)),
+        "kv_norm": Spec((cfg.kv_lora_rank,), P(None), scale=0.0),
+        "wkvb": Spec((cfg.kv_lora_rank,
+                      h * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                     P(None, "model")),
+        "wo": Spec((h * cfg.v_head_dim, d), P("model", None)),
+        "norm": Spec((d,), P(None), scale=0.0),
+    }
+
+
+def _self_attn_specs(cfg: ArchConfig, tp: int) -> dict:
+    return _mla_specs(cfg, tp) if cfg.mla else _attn_specs(cfg, tp)
+
+
 def _mlp_specs(cfg: ArchConfig, tp: int) -> dict:
     d, ff = cfg.d_model, pad_to(cfg.d_ff, tp)
     # gate/up kept as separate leaves: a fused (d, 2ff) matrix cannot be
@@ -252,17 +302,24 @@ def _mlp_specs(cfg: ArchConfig, tp: int) -> dict:
 
 
 def _moe_specs(cfg: ArchConfig, tp: int) -> dict:
-    d, ff = cfg.d_model, cfg.d_ff  # cfg.d_ff is the per-expert ff dim
-    ne = padded_experts(cfg, tp)
-    return {
-        "router": Spec((d, ne), P(None, None)),  # replicated; grad psum'd
+    d, ff = cfg.d_model, cfg.expert_ff
+    ne = stored_experts(cfg, tp)
+    s = {
+        # scores every expert; replicated, grad psum'd
+        "router": Spec((d, padded_experts(cfg, tp)), P(None, None)),
         "experts": {
-            # experts sharded over 'model' on the expert axis (EP over TP)
+            # stored experts split over 'model' on the expert axis (EP)
             "wi": Spec((ne, d, 2 * ff), P("model", None, None)),
             "wo": Spec((ne, ff, d), P("model", None, None)),
         },
         "norm": Spec((d,), P(None), scale=0.0),
     }
+    if cfg.n_shared_experts:
+        fs = pad_to(cfg.n_shared_experts * ff, tp)
+        s["shared"] = {"wg": Spec((d, fs), P(None, "model")),
+                       "wu": Spec((d, fs), P(None, "model")),
+                       "wo": Spec((fs, d), P("model", None))}
+    return s
 
 
 def _rwkv_specs(cfg: ArchConfig, tp: int) -> dict:
@@ -317,7 +374,8 @@ _BLOCK_SPECS = {
     "attn": lambda c, t: {**_attn_specs(c, t), **{"mlp": _mlp_specs(c, t)}},
     "cross": lambda c, t: {**_attn_specs(c, t, cross=True),
                            **{"mlp": _mlp_specs(c, t)}},
-    "moe": lambda c, t: {**_attn_specs(c, t), **{"moe": _moe_specs(c, t)}},
+    "moe": lambda c, t: {**_self_attn_specs(c, t),
+                         **{"moe": _moe_specs(c, t)}},
     "rwkv": lambda c, t: _rwkv_specs(c, t),
     "mamba": lambda c, t: _mamba_specs(c, t),
 }
@@ -355,6 +413,9 @@ def param_specs(cfg: ArchConfig, tp: int = 1) -> dict:
         sub = _BLOCK_SPECS[kind](cfg, tp)
         layer[kind] = _stack(sub, cnt)
     specs["layers"] = _stack(layer, cfg.n_cycles)
+    if cfg.first_dense:   # leading dense layers, applied before the cycles
+        specs["lead"] = _stack({**_self_attn_specs(cfg, tp),
+                                "mlp": _mlp_specs(cfg, tp)}, cfg.first_dense)
 
     if "shared_attn" in cfg.cycle:
         specs["shared_attn"] = {**_attn_specs(cfg, tp),

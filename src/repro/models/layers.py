@@ -46,6 +46,31 @@ def rope(x: Array, pos: Array, theta: float) -> Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
+def rope_interleaved(x: Array, pos: Array, theta: float) -> Array:
+    """Rotary embedding on interleaved pairs (2i, 2i+1), each turned by
+    pos * theta^(-2i/hd) (``rope_interleave``). x: (B, S, H, hd)."""
+    hd = x.shape[-1]
+    half = hd // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = pos[..., None].astype(jnp.float32) * freqs  # (B, S, half)
+    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
+    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     -1).reshape(x.shape)
+
+
+def per_row(fn, *rows):
+    """``fn`` over one batch row at a time, each given as a batch of one,
+    its body remat'd: the activations of one sequence are live at once.
+    Every output of ``fn`` carries that leading axis of one; the rows'
+    outputs come back concatenated on it."""
+    out = jax.lax.map(jax.checkpoint(lambda r: fn(*(a[None] for a in r))),
+                      rows)
+    return jax.tree_util.tree_map(
+        lambda o: o.reshape((-1,) + o.shape[2:]), out)
+
+
 def linear_row(x: Array, w: Array, ctx: ShardCtx) -> Array:
     """Row-parallel matmul: local contraction + psum over the model axis."""
     return ctx.psum_tp(x @ w.astype(x.dtype))
@@ -81,7 +106,8 @@ def _attn_chunk(q, k_c, v_c, bias_c, scale, dtype):
 
 def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
                         q_pos: Array, kv_pos: Array, chunk: int = 1024) -> Array:
-    """Flash-style attention. q: (B,S,Hq,hd); k,v: (B,T,Hkv,hd). -> (B,S,Hq,hd)
+    """Flash-style attention. q, k: (B,S,Hq,hd), (B,T,Hkv,hd); v: (B,T,Hkv,dv)
+    -> (B,S,Hq,dv)
 
     GQA is computed in grouped form — KV heads are never replicated in
     memory. Online softmax over KV chunks keeps live memory O(S*chunk); each
@@ -105,7 +131,7 @@ def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
         kv_pos = jnp.pad(kv_pos, ((0, 0), (0, pad)), constant_values=-1)
     n_chunks = (T + pad) // chunk
     kt = kt.reshape(B, Hkv, n_chunks, chunk, hd).transpose(2, 0, 1, 3, 4)
-    vt = vt.reshape(B, Hkv, n_chunks, chunk, hd).transpose(2, 0, 1, 3, 4)
+    vt = vt.reshape(B, Hkv, n_chunks, chunk, -1).transpose(2, 0, 1, 3, 4)
     kv_pos_c = kv_pos.reshape(B, n_chunks, chunk).transpose(1, 0, 2)
 
     def body(carry, xs):
@@ -126,12 +152,34 @@ def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
                  + o_c * b[..., None].astype(q.dtype))
         return (m_new, l_new, o_new), None
 
+    dv = v.shape[-1]
     m0 = jnp.full((B, Hkv, rep, S), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Hkv, rep, S), jnp.float32)
-    o0 = jnp.zeros((B, Hkv, rep, S, hd), q.dtype)
+    o0 = jnp.zeros((B, Hkv, rep, S, dv), q.dtype)
     (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0), (kt, vt, kv_pos_c))
     out = o / jnp.maximum(l, 1e-20)[..., None].astype(q.dtype)
-    return out.reshape(B, Hq, S, hd).transpose(0, 2, 1, 3)
+    return out.reshape(B, Hq, S, dv).transpose(0, 2, 1, 3)
+
+
+def causal_prefix_attention(q: Array, k: Array, v: Array, pos: Array, *,
+                            block: int = 1024) -> Array:
+    """Causal self-attention with the queries in blocks: block i attends
+    to the keys of blocks 0..i only (``blockwise_attention`` over that
+    prefix), so neither an (S, S) score matrix nor a (block, S) one over
+    keys a causal mask would void is formed. q, k: (B, S, H, dqk);
+    v: (B, S, H, dv) -> (B, S, H, dv)."""
+    S = q.shape[1]
+
+    # remat'd per block: its backward recomputes one block's scan at a
+    # time and keeps only the shared q, k, v
+    @functools.partial(jax.checkpoint, static_argnums=(4, 5))
+    def one(q, k, v, pos, a, b):
+        return blockwise_attention(
+            q[:, a:b], k[:, :b], v[:, :b], causal=True, q_pos=pos[:, a:b],
+            kv_pos=pos[:, :b], chunk=block)
+
+    return jnp.concatenate([one(q, k, v, pos, a, min(a + block, S))
+                            for a in range(0, S, block)], axis=1)
 
 
 def decode_attention(q: Array, k_cache: Array, v_cache: Array,
@@ -244,6 +292,45 @@ def attention_block(p: dict, cfg: ArchConfig, ctx: ShardCtx, x: Array,
 
     y = linear_row(o.reshape(B, S, -1), p["wo"], ctx)
     return x + y.astype(x.dtype), new_cache
+
+
+def _mla_attend(p: dict, cfg: ArchConfig, ctx: ShardCtx, h: Array,
+                pos: Array) -> Array:
+    """Latent attention of normed rows h: (B, S, d) -> the row-parallel
+    output projection (before the residual)."""
+    B, S, _ = h.shape
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    q = (h @ p["wq"].astype(h.dtype)).reshape(B, S, -1, dn + dr)
+    H = q.shape[2]
+    kva = h @ p["wkva"].astype(h.dtype)                  # [c_kv, k_pe]
+    c_kv = rmsnorm(kva[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    kvb = (c_kv @ p["wkvb"].astype(h.dtype)).reshape(B, S, H, dn + dv)
+    q_pe = rope_interleaved(q[..., dn:], pos, cfg.rope_theta)
+    k_pe = rope_interleaved(kva[..., None, cfg.kv_lora_rank:], pos,
+                            cfg.rope_theta)
+    qf = jnp.concatenate([q[..., :dn], q_pe], -1)
+    kf = jnp.concatenate([kvb[..., :dn],
+                          jnp.broadcast_to(k_pe, (B, S, H, dr))], -1)
+    o = causal_prefix_attention(qf, kf, kvb[..., dn:], pos)
+    return linear_row(o.reshape(B, S, H * dv), p["wo"], ctx)
+
+
+def mla_block(p: dict, cfg: ArchConfig, ctx: ShardCtx, x: Array, pos: Array,
+              *, mode: str = "train") -> Array:
+    """Pre-norm latent attention block (DeepSeek-V2 MLA, no query
+    compression): q = W_q h split into [q_nope, q_pe]; [c_kv, k_pe] =
+    W_kva h; [k_nope, v] = W_kvb RMSNorm(c_kv); rotary on q_pe and on the
+    one k_pe every head shares, on interleaved pairs (DeepSeek-V3's
+    ``rope_interleave``); causal softmax of [q_nope, q_pe] .
+    [k_nope, k_pe] over sqrt(qk_nope + qk_rope); o = W_o. One sequence at
+    a time (``per_row``); training only (no latent cache)."""
+    if mode != "train":
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention has no serving cache")
+    with jax.named_scope("mla"):
+        h = rmsnorm(x, p["norm"], cfg.norm_eps)
+        y = per_row(lambda hr, pr: _mla_attend(p, cfg, ctx, hr, pr), h, pos)
+    return x + y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

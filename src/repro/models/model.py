@@ -35,8 +35,9 @@ from repro.models import rwkv as rk
 from repro.models.common import ArchConfig, ShardCtx, head_geometry
 from repro.models.flatten import FlatSpec
 from repro.models.layers import (attention_block, embed_lookup, lm_logits,
-                                 lm_loss, mlp_block, parallel_attn_mlp_block,
-                                 rmsnorm, sharded_argmax)
+                                 lm_loss, mla_block, mlp_block,
+                                 parallel_attn_mlp_block, rmsnorm,
+                                 sharded_argmax)
 
 Array = jax.Array
 Gathers = tuple[Callable[[Array], Array], Callable[[Array], Array]] | None
@@ -52,12 +53,49 @@ def _kind_counts(cfg: ArchConfig) -> dict[str, int]:
     return counts
 
 
+def _servable(cfg: ArchConfig) -> None:
+    """Serving has no latent cache and no selection-bias state."""
+    if cfg.mla or cfg.router == "sigmoid":
+        raise NotImplementedError(
+            f"{cfg.name}: serving latent attention (MLA) or a sigmoid "
+            f"router with its selection bias is not supported; the model "
+            f"is trained only")
+
+
+def _self_attention(p: dict, cfg: ArchConfig, ctx: ShardCtx, x: Array,
+                    pos: Array, mode: str, cache: dict | None,
+                    kv_len: Array | None) -> tuple[Array, dict | None]:
+    if cfg.mla:
+        return mla_block(p, cfg, ctx, x, pos, mode=mode), None
+    return attention_block(p, cfg, ctx, x, pos, mode=mode, cache=cache,
+                           kv_len=kv_len)
+
+
+def _apply_lead(cfg: ArchConfig, ctx: ShardCtx, top: dict, x: Array,
+                pos: Array, mode: str, remat: bool = False) -> Array:
+    """The leading dense layers (``first_dense``), before the cycles, each
+    remat'd like a cycle where ``remat``."""
+    def layer(p, x):
+        x, _ = _self_attention(p, cfg, ctx, x, pos, mode, None, None)
+        return mlp_block(p["mlp"], cfg, ctx, x)
+
+    if remat:
+        layer = jax.checkpoint(layer)
+    for i in range(cfg.first_dense):
+        x = layer(jax.tree_util.tree_map(lambda a: a[i], top["lead"]), x)
+    return x
+
+
 def _apply_cycle(cfg: ArchConfig, ctx: ShardCtx, cyc_p: dict,
                  shared_p: dict | None, x: Array, pos: Array, mode: str,
                  cross_kv: Array | None, cache: dict | None,
-                 kv_len: Array | None) -> tuple[Array, Array, dict | None]:
-    """Apply one cycle of blocks. Returns (x, aux_loss, new_cache)."""
+                 kv_len: Array | None, bias: Array | None = None
+                 ) -> tuple[Array, Array, Array | None, dict | None]:
+    """Apply one cycle of blocks. Returns (x, aux_loss, load, new_cache):
+    load, of the cycle's MoE block, is the tokens routed to each expert
+    (None without one); ``bias`` is that router's selection bias."""
     aux = jnp.float32(0.0)
+    load = None
     new_cache: dict[str, Any] = {}
     occ: dict[str, int] = {}
 
@@ -101,9 +139,9 @@ def _apply_cycle(cfg: ArchConfig, ctx: ShardCtx, cyc_p: dict,
                                    cross_kv=cross_kv)
             x = mlp_block(p["mlp"], cfg, ctx, x)
         elif kind == "moe":
-            x, c = attention_block(p, cfg, ctx, x, pos, mode=mode,
-                                   cache=sub(kind, j), kv_len=kv_len)
-            x, a = moe_lib.moe_block(p["moe"], cfg, ctx, x)
+            x, c = _self_attention(p, cfg, ctx, x, pos, mode, sub(kind, j),
+                                   kv_len)
+            x, a, load = moe_lib.moe_block(p["moe"], cfg, ctx, x, bias)
             aux = aux + a
             put(kind, j, c)
         elif kind == "rwkv":
@@ -116,28 +154,29 @@ def _apply_cycle(cfg: ArchConfig, ctx: ShardCtx, cyc_p: dict,
             put(kind, j, c)
         else:  # pragma: no cover
             raise ValueError(f"unknown block kind {kind!r}")
-    return x, aux, (new_cache if cache is not None else None)
+    return x, aux, load, (new_cache if cache is not None else None)
 
 
 def _cycle_scan_body(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
                      shared_p: dict | None, pos: Array, mode: str,
                      cross_kv: Array | None, kv_len: Array | None,
                      gs_, gr_):
-    """Scan body over (vs, vr, cyc_cache) triples — the single source of
+    """Scan body over (vs, vr, cyc_cache, bias) — the single source of
     the per-cycle step, shared by ``_backbone`` and the chunked training
-    path so the two cannot drift."""
+    path so the two cannot drift. Its outputs are (new cache, MoE load)."""
     def body(carry, xs):
         x, aux = carry
-        vs, vr, cyc_cache = xs
+        vs, vr, cyc_cache, bias = xs
         cyc_p = fs.cycle_params(gs_(vs), gr_(vr), ctx.dtype)
-        x, a, new_c = _apply_cycle(cfg, ctx, cyc_p, shared_p, x, pos, mode,
-                                   cross_kv, cyc_cache, kv_len)
-        return (x, aux + a), new_c
+        x, a, load, new_c = _apply_cycle(cfg, ctx, cyc_p, shared_p, x, pos,
+                                         mode, cross_kv, cyc_cache, kv_len,
+                                         bias)
+        return (x, aux + a), (new_c, load)
 
     return body
 
 
-def _scan_cycles(cyc, carry, cs: Array, cr: Array, remat: bool):
+def _scan_cycles(cyc, carry, xs, remat: bool):
     """Scan ``cyc`` over cycle rows with the sqrt-n nested-remat structure.
 
     A flat scan's backward stores the carry at every cycle (n * B*S*d —
@@ -145,34 +184,40 @@ def _scan_cycles(cyc, carry, cs: Array, cr: Array, remat: bool):
     stores ~(n1 + n2) carries instead. Shared by the monolithic training
     scan and each chunk of ``chunked_loss_vjp`` (applied within the chunk's
     cycle range, so the chunk VJP's residual footprint stays sublinear).
+    The scan nests only where that stores fewer carries than the n of a
+    flat scan: at four and five cycles both store n, and nesting would
+    only recompute every cycle once more.
+    ``xs`` holds one row per cycle; returns (carry, per-cycle outputs).
     """
-    n = cs.shape[0]
-    n2 = int(math.isqrt(n))
-    if remat and n2 >= 2:
-        n1, rem = n // n2, n % n2
-
-        def outer(c, vs):
-            c, _ = jax.lax.scan(cyc, c, vs)
-            return c, None
-
-        main = jax.tree_util.tree_map(
-            lambda a: a[:n1 * n2].reshape((n1, n2) + a.shape[1:]),
-            (cs, cr))
-        carry, _ = jax.lax.scan(jax.checkpoint(outer), carry, main)
-        if rem:
-            tail = jax.tree_util.tree_map(lambda a: a[n1 * n2:], (cs, cr))
-            carry, _ = jax.lax.scan(cyc, carry, tail)
-    else:
-        carry, _ = jax.lax.scan(cyc, carry, (cs, cr))
-    return carry
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    n2 = max(1, int(math.isqrt(n)))
+    n1, rem = n // n2, n % n2
+    if not (remat and n1 + n2 + rem < n):
+        return jax.lax.scan(cyc, carry, xs)
+    main = jax.tree_util.tree_map(
+        lambda a: a[:n1 * n2].reshape((n1, n2) + a.shape[1:]), xs)
+    carry, ys = jax.lax.scan(
+        jax.checkpoint(lambda c, v: jax.lax.scan(cyc, c, v)), carry, main)
+    ys = jax.tree_util.tree_map(
+        lambda a: a.reshape((n1 * n2,) + a.shape[2:]), ys)
+    if rem:
+        tail = jax.tree_util.tree_map(lambda a: a[n1 * n2:], xs)
+        carry, yt = jax.lax.scan(cyc, carry, tail)
+        ys = jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b]), ys, yt)
+    return carry, ys
 
 
 def _backbone(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
               tokens: Array, pos: Array, mode: str,
               cross_kv: Array | None = None, cache: Any = None,
               kv_len: Array | None = None, gathers: Gathers = None,
-              remat: bool = False) -> tuple[Array, Array, Any, dict]:
-    """Embed -> scan cycles -> final norm. Returns (hidden, aux, cache, top).
+              remat: bool = False, router_bias: Array | None = None
+              ) -> tuple[Array, Array, Any, dict, Array | None]:
+    """Embed -> leading dense layers -> scan cycles -> final norm. Returns
+    (hidden, aux, cache, top, load): load (n_cycles, E) is the tokens each
+    cycle's router sent to each expert (None without MoE cycles);
+    ``router_bias`` (n_cycles, E) the routers' selection bias.
 
     segs: flat-segment dict (see flatten.py). gathers = (gather_sharded,
     gather_replicated) — identity when storage is unsharded (tp=1 smoke /
@@ -182,6 +227,7 @@ def _backbone(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
     top = fs.top_params(gs_(segs["top_s"]), gr_(segs["top_r"]), ctx.dtype)
 
     x = embed_lookup(top["embed"], tokens, ctx)
+    x = _apply_lead(cfg, ctx, top, x, pos, mode, remat)
     shared_p = top.get("shared_attn")
 
     body = _cycle_scan_body(cfg, ctx, fs, shared_p, pos, mode, cross_kv,
@@ -200,7 +246,8 @@ def _backbone(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
                 lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
                                                        keepdims=False),
                 cache_full)
-            (x, aux), new_c = body((x, aux), (xs[0], xs[1], cyc_cache))
+            (x, aux), (new_c, _) = body((x, aux),
+                                        (xs[0], xs[1], cyc_cache, None))
             cache_full = jax.tree_util.tree_map(
                 lambda full, nc: jax.lax.dynamic_update_index_in_dim(
                     full, nc.astype(full.dtype), i, 0),
@@ -211,14 +258,16 @@ def _backbone(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
             serve_body, (x, jnp.float32(0.0), cache, jnp.int32(0)),
             (cs, cr))
         x = rmsnorm(x, top["final_norm"], cfg.norm_eps)
-        return x, aux, new_cache, top
-    if cache is None:
-        def cyc(c, v):
-            return body(c, (v[0], v[1], None))
+        return x, aux, new_cache, top, None
 
-        x, aux = _scan_cycles(cyc, (x, jnp.float32(0.0)), cs, cr, remat)
+    def cyc(c, v):
+        c, (_, load) = body(c, (v[0], v[1], None, v[2]))
+        return c, load
+
+    (x, aux), load = _scan_cycles(cyc, (x, jnp.float32(0.0)),
+                                  (cs, cr, router_bias), remat)
     x = rmsnorm(x, top["final_norm"], cfg.norm_eps)
-    return x, aux, None, top
+    return x, aux, None, top, load
 
 
 def _head_w(cfg: ArchConfig, top: dict) -> Array:
@@ -241,22 +290,26 @@ def _loss_head(cfg: ArchConfig, ctx: ShardCtx, hid: Array, aux: Array,
 
 
 def loss_fn(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
-            batch: dict, *, gathers: Gathers = None,
-            remat: bool = True) -> Array:
-    """Mean next-token CE (+ MoE aux). batch: tokens/labels (B,S) [cross_kv]."""
+            batch: dict, *, gathers: Gathers = None, remat: bool = True,
+            router_bias: Array | None = None, with_load: bool = False):
+    """Mean next-token CE (+ MoE aux). batch: tokens/labels (B,S) [cross_kv].
+    ``with_load``: (loss, load), load as ``_backbone`` gives it."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    hid, aux, _, top = _backbone(cfg, ctx, fs, segs, tokens, pos, "train",
-                                 cross_kv=batch.get("cross_kv"),
-                                 gathers=gathers, remat=remat)
-    return _loss_head(cfg, ctx, hid, aux, top, batch["labels"])
+    hid, aux, _, top, load = _backbone(
+        cfg, ctx, fs, segs, tokens, pos, "train",
+        cross_kv=batch.get("cross_kv"), gathers=gathers, remat=remat,
+        router_bias=router_bias)
+    loss = _loss_head(cfg, ctx, hid, aux, top, batch["labels"])
+    return (loss, load) if with_load else loss
 
 
 def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
                      segs: dict, batch: dict, *, chunks: int,
                      gathers: Gathers = None, remat: bool = True,
-                     grad_seed: float = 1.0):
+                     grad_seed: float = 1.0,
+                     router_bias: Array | None = None):
     """Training forward with the cycle scan split into K autodiff chunks.
 
     The monolithic ``loss_fn`` hands autodiff one opaque scan, so the full
@@ -270,7 +323,7 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
     pending; within each chunk the sqrt-n ``_scan_cycles`` remat structure
     is preserved.
 
-    Returns ``(loss, bwd_steps, top_grads)``:
+    Returns ``(loss, bwd_steps, top_grads, load)``:
 
       loss       — scalar, identical to ``loss_fn`` (before grad_seed).
       bwd_steps  — K thunks to invoke STRICTLY in order. Step j runs the
@@ -283,6 +336,8 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
                    accumulated ``(d_top_s, d_top_r)`` (embed + head +
                    shared leaves receive contributions from every chunk,
                    so they finalize last — the final emission event).
+      load       — the MoE cycles' routed tokens per expert, as
+                   ``loss_fn(..., with_load=True)`` gives it (or None).
 
     grad_seed scales the loss cotangent (the caller's 1/tp seeding).
     Gradients equal ``jax.grad(grad_seed * loss_fn)`` exactly: the chunk
@@ -317,9 +372,11 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
     def prologue(ts, tr):
         with jax.named_scope("forward"):
             top = fs.top_params(ts, tr, ctx.dtype)
-            return embed_lookup(top["embed"], tokens, ctx), jnp.float32(0.0)
+            x = embed_lookup(top["embed"], tokens, ctx)
+            return (_apply_lead(cfg, ctx, top, x, pos, "train", remat),
+                    jnp.float32(0.0))
 
-    def chunk_fn(carry, vs, vr, ts, tr):
+    def chunk_fn(carry, vs, vr, ts, tr, bias):
         with jax.named_scope("forward"):
             top = fs.top_params(ts, tr, ctx.dtype)
             body = _cycle_scan_body(cfg, ctx, fs, top.get("shared_attn"),
@@ -328,9 +385,10 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
                 body = jax.checkpoint(body)
 
             def cyc(c, v):
-                return body(c, (v[0], v[1], None))
+                c, (_, load) = body(c, (v[0], v[1], None, v[2]))
+                return c, load
 
-            return _scan_cycles(cyc, carry, vs, vr, remat)
+            return _scan_cycles(cyc, carry, (vs, vr, bias), remat)
 
     def epilogue(carry, ts, tr):
         with jax.named_scope("forward"):
@@ -340,11 +398,16 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
             return _loss_head(cfg, ctx, x, aux, top, batch["labels"])
 
     carry, vjp_pro = jax.vjp(prologue, g_ts, g_tr)
-    chunk_vjps = []
+    chunk_vjps, loads = [], []
     for a, b in bounds:
-        carry, vjp_c = jax.vjp(chunk_fn, carry, cs[a:b], cr[a:b], g_ts, g_tr)
+        bias = None if router_bias is None else router_bias[a:b]
+        carry, vjp_c, load = jax.vjp(
+            lambda c, vs, vr, ts, tr: chunk_fn(c, vs, vr, ts, tr, bias),
+            carry, cs[a:b], cr[a:b], g_ts, g_tr, has_aux=True)
         chunk_vjps.append(vjp_c)
+        loads.append(load)
     loss, vjp_epi = jax.vjp(epilogue, carry, g_ts, g_tr)
+    load = None if loads[0] is None else jnp.concatenate(loads)
 
     st: dict = {}
 
@@ -371,7 +434,7 @@ def chunked_loss_vjp(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec,
     def top_grads():
         return vjp_gather((st["d_ts"], st["d_tr"]))
 
-    return loss, [make_step(j) for j in range(K)], top_grads
+    return loss, [make_step(j) for j in range(K)], top_grads, load
 
 
 def prefill_fn(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
@@ -379,13 +442,14 @@ def prefill_fn(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
                gathers: Gathers = None) -> tuple[Array, Any]:
     """Prompt forward; fills ``cache`` from position 0. Returns (last-token
     logits (B, V_local), new cache)."""
+    _servable(cfg)
     tokens = batch["tokens"]
     B, S = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    hid, _, cache, top = _backbone(cfg, ctx, fs, segs, tokens, pos, "prefill",
-                                   cross_kv=batch.get("cross_kv"),
-                                   cache=cache, kv_len=jnp.int32(0),
-                                   gathers=gathers)
+    hid, _, cache, top, _ = _backbone(
+        cfg, ctx, fs, segs, tokens, pos, "prefill",
+        cross_kv=batch.get("cross_kv"), cache=cache, kv_len=jnp.int32(0),
+        gathers=gathers)
     logits = lm_logits(hid[:, -1:, :], _head_w(cfg, top), cfg, ctx)
     return logits[:, 0, :], cache
 
@@ -401,12 +465,13 @@ def decode_fn(cfg: ArchConfig, ctx: ShardCtx, fs: FlatSpec, segs: dict,
     (whole batch at one position — the original demo path) or a (B,)
     vector (each row at its own position — continuous batching).
     """
+    _servable(cfg)
     B, S = tokens.shape
     kl = jnp.asarray(kv_len).astype(jnp.int32)
     pos = jnp.broadcast_to(kl[:, None] if kl.ndim else kl, (B, S))
-    hid, _, cache, top = _backbone(cfg, ctx, fs, segs, tokens, pos, "decode",
-                                   cross_kv=cross_kv, cache=cache,
-                                   kv_len=kv_len, gathers=gathers)
+    hid, _, cache, top, _ = _backbone(
+        cfg, ctx, fs, segs, tokens, pos, "decode", cross_kv=cross_kv,
+        cache=cache, kv_len=kv_len, gathers=gathers)
     logits = lm_logits(hid, _head_w(cfg, top), cfg, ctx)
     return sharded_argmax(logits[:, 0, :], ctx), cache
 
@@ -423,6 +488,7 @@ def init_cache(cfg: ArchConfig, ctx: ShardCtx, b_loc: int, t_cache: int,
     Attention/moe kinds get KV caches; rwkv/mamba get recurrent states;
     cross blocks need none (static image KV).
     """
+    _servable(cfg)
     n = cfg.n_cycles
     g = head_geometry(cfg, ctx.tp)
     nkv_store = 1 if g.kv_replicated else g.nkv_loc

@@ -6,12 +6,14 @@ from repro.obs.drift import (DEFAULT_PHASES, DriftDetector, DriftEvent,
 from repro.obs.metrics import (TRACE2_SCHEMA, Metrics, compile_counts, dump,
                                load_jsonl, trace2_doc)
 from repro.obs.provenance import provenance, runspec_hash
-from repro.obs.trace import (NULL, PHASES, SCOPES, TRACE_SCHEMA, Tracer,
-                             current, from_sim, phase, validate)
+from repro.obs.trace import (BLOCK_SCOPES, NULL, PHASES, SCOPES,
+                             TRACE_SCHEMA, Tracer, current, from_sim, phase,
+                             validate)
 
 __all__ = [
     "trace", "Tracer", "current", "from_sim", "validate", "NULL",
-    "PHASES", "SCOPES", "phase", "TRACE_SCHEMA", "TRACE2_SCHEMA",
+    "PHASES", "SCOPES", "BLOCK_SCOPES", "phase", "TRACE_SCHEMA",
+    "TRACE2_SCHEMA",
     "Metrics", "compile_counts", "trace2_doc",
     "dump", "load_jsonl", "provenance", "runspec_hash",
     "DEFAULT_PHASES", "DriftDetector", "DriftEvent", "detection_bound",

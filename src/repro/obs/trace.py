@@ -58,6 +58,17 @@ same taxonomy:
                           and the EF residual
     optimizer             mean, norm, clip, unpack, optimizer update
 
+Scopes of block kinds, entered only where a model has them (``BLOCK_SCOPES``;
+each holds its part of the forward, its remat recompute and its backward):
+
+    mla                   latent attention: projections, rotary, attention,
+                          output projection
+    moe_route             router, scores, top-k, gates, load count and the
+                          selection-bias update
+    moe_dispatch          grouping and ungrouping of the routed rows, the
+                          combine
+    moe_experts           the routed grouped matmuls and the shared experts
+
 ``from_sim(result)`` renders a ``sim.cluster.SimResult`` into the same
 schema, so a measured trace and a simulated one for the same RunSpec are
 structurally identical (schema-equality is a tier-1 test).
@@ -77,8 +88,10 @@ TRACE_SCHEMA = "repro.obs/trace@1"
 PHASES = ("forward", "backward", "encode", "comm", "recover")
 
 # Device scopes of the train step (module docstring).
+BLOCK_SCOPES = ("mla", "moe_route", "moe_dispatch", "moe_experts")
 SCOPES = ("forward", "backward", "encode", "comm", "recover/decode",
-          "recover/select", "recover/second_round", "optimizer")
+          "recover/select", "recover/second_round",
+          "optimizer") + BLOCK_SCOPES
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Per-architecture smoke tests (reduced same-family configs, CPU).
 
-For each of the ten assigned archs: forward loss (finite, ~log V at init),
+For each registered arch's smoke config: forward loss (finite, ~log V at init),
 one train step (loss decreases over a few steps), prefill/decode
 consistency (incremental decoding reproduces the full-forward argmax).
 """
@@ -19,6 +19,8 @@ from repro.optim import make as make_opt
 
 CTX = ShardCtx(tp=1, tp_axis=None, dtype=jnp.float32)
 ALL = sorted(SMOKES)
+# serving has no latent cache (tests/test_mla_moe.py pins the refusal)
+SERVED = [n for n in ALL if not SMOKES[n].mla]
 
 
 def _batch(cfg, B=2, S=16, seed=1):
@@ -51,7 +53,7 @@ def test_train_step_reduces_loss(name):
     ts = make_train_step(cfg, ma, opt, dp_mode="dp", compressor_name=None,
                          remat=True, dtype=jnp.float32)
     params = init_flat_params(cfg, jax.random.PRNGKey(0), 1, ts.fs)
-    state = make_state(params, opt, None, ts.d_local)
+    state = make_state(params, opt, None, ts.d_local, cfg=cfg)
     step = jax.jit(ts.fn)
     batch = _batch(cfg, B=2, S=16)
     losses = []
@@ -64,7 +66,7 @@ def test_train_step_reduces_loss(name):
         assert bool(jnp.all(jnp.isfinite(v))), k
 
 
-@pytest.mark.parametrize("name", ALL)
+@pytest.mark.parametrize("name", SERVED)
 def test_prefill_decode_consistency(name):
     cfg = SMOKES[name]
     fs = make_flat_spec(cfg, 1)
@@ -107,6 +109,7 @@ def test_full_config_parameter_counts(name):
         "rwkv6-7b": (6e9, 9e9),
         "musicgen-large": (2.5e9, 4e9),
         "zamba2-2.7b": (2e9, 3.5e9),
+        "kanana-2-30b-a3b": (29e9, 32e9),
     }[name]
     assert expected[0] < n < expected[1], f"{name}: {n / 1e9:.2f}B params"
 
@@ -120,6 +123,6 @@ def test_moe_aux_loss_present():
     cyc = fs.cycle_params(segs["cycles_s"][0], segs["cycles_r"][0],
                           jnp.float32)
     p = jax.tree_util.tree_map(lambda a: a[0], cyc["moe"])  # occurrence 0
-    y, aux = moe_lib.moe_block(p["moe"], cfg, CTX, h)
+    y, aux, _ = moe_lib.moe_block(p["moe"], cfg, CTX, h)
     assert y.shape == h.shape
     assert float(aux) > 0.0  # Switch aux loss >= 1 at balance, > 0 always

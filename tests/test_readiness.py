@@ -35,8 +35,8 @@ def _grads_of(chunks, remat=False):
     if chunks is None:
         return jax.value_and_grad(
             lambda p: mdl.loss_fn(CFG, ctx, fs, p, batch, remat=remat))(segs)
-    loss, steps, top = mdl.chunked_loss_vjp(CFG, ctx, fs, segs, batch,
-                                            chunks=chunks, remat=remat)
+    loss, steps, top, _ = mdl.chunked_loss_vjp(CFG, ctx, fs, segs, batch,
+                                               chunks=chunks, remat=remat)
     d_cs = jnp.zeros_like(segs["cycles_s"])
     d_cr = jnp.zeros_like(segs["cycles_r"])
     spans = []

@@ -17,7 +17,7 @@ from repro.api import ClusterSpec, ExchangeSpec, RunSpec
 from repro.data import LMStream
 from repro.launch import train
 
-GS = set(obs.SCOPES)
+GS = set(obs.SCOPES) - set(obs.BLOCK_SCOPES)   # musicgen has no such block
 DENSE = {"forward", "backward", "comm", "optimizer"}
 EXCHANGES = {
     "monolithic": ExchangeSpec(compressor="gs-sgd"),
@@ -29,11 +29,12 @@ EXCHANGES = {
 _OPS: dict = {}
 
 
-def step_ops(name: str) -> collections.Counter:
+def step_ops(name: str, arch: str = "musicgen-large",
+             layers: int | None = 1) -> collections.Counter:
     """{(phase, the op's primitive): number of HLO instructions} of the
     compiled two-worker step, compiled once a process."""
-    if name not in _OPS:
-        spec = RunSpec(arch="musicgen-large", smoke=True, layers=1, batch=4,
+    if (name, arch) not in _OPS:
+        spec = RunSpec(arch=arch, smoke=True, layers=layers, batch=4,
                        seq=16, cluster=ClusterSpec(p=2),
                        exchange=EXCHANGES[name])
         cfg, opt, _, ts = train.build(spec)
@@ -43,10 +44,10 @@ def step_ops(name: str) -> collections.Counter:
         batch = train.worker_batch(stream, 0, spec)
         text = train.make_step_fn(ts, 2).lower(state,
                                                batch).compile().as_text()
-        _OPS[name] = collections.Counter(
+        _OPS[name, arch] = collections.Counter(
             (phases.phase_of(path), path.rsplit("/", 1)[-1])
             for path in phases.scope_paths(text).values())
-    return _OPS[name]
+    return _OPS[name, arch]
 
 
 @pytest.mark.parametrize("exchange, want", [
@@ -85,3 +86,13 @@ def test_dense_pack_is_not_comm():
     # comm holds the psum alone
     ops = step_ops("dense")
     assert not ops[("comm", "concatenate")]
+
+
+def test_block_scopes_are_named_in_a_latent_attention_moe_step():
+    # kanana's blocks: latent attention, routing, dispatch and the experts'
+    # grouped matmuls each carry their scope; the gs-SGD phases still do
+    ops = step_ops("monolithic", "kanana-2-30b-a3b", None)
+    got = {ph for (ph, _), n in ops.items() if n}
+    assert set(obs.SCOPES) <= got
+    assert ops[("moe_dispatch", "sort")] >= 1
+    assert ops[("moe_route", "sort")] >= 1 or ops[("moe_route", "top_k")] >= 1

@@ -24,7 +24,7 @@ from repro.models.model import decode_fn, init_cache, loss_fn, prefill_fn
 from repro.optim import make as make_opt
 
 TP = 2
-# granite excluded: 5 experts pad 5->6 at tp=2 (different capacity math)
+# granite excluded: 5 experts pad 5->6 at tp=2 (a wider router)
 ARCHS_TP = ["qwen3-4b", "starcoder2-3b", "yi-9b", "minicpm-2b",
             "musicgen-large", "rwkv6-7b", "zamba2-2.7b",
             "qwen3-moe-235b-a22b", "llama-3.2-vision-11b"]
