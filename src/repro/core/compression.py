@@ -225,7 +225,8 @@ class _SketchBased:
             with jax.named_scope("recover/decode"):
                 est = ts.decode(self._ts_cfg(d), sketch_sum, d)
         idx, _ = hm.heavymix(self.sketch, sketch_sum, self.k, d, key=key,
-                             faithful=self.faithful_heavymix, estimates=est)
+                             faithful=self.faithful_heavymix, estimates=est,
+                             use_pallas=self.use_pallas or None)
         # Second round (Alg.2 line 4): exact values of Top_k, k floats.
         with jax.named_scope("recover/second_round"):
             vals = u[idx] if include is None else u[idx] * include
@@ -451,7 +452,8 @@ class FetchSGDStyle(_SketchBased):
         with obtrace.phase("recover"):
             s_m = self.momentum * s_m + sk             # momentum in-sketch
             s_e = s_e + s_m                            # error accumulation
-            idx, est = hm.heavymix(self.sketch, s_e, self.k, d, key=key)
+            idx, est = hm.heavymix(self.sketch, s_e, self.k, d, key=key,
+                                   use_pallas=self.use_pallas or None)
             upd = _scatter(d, idx, est)
         with obtrace.phase("encode", "encode/applied"):
             s_e = s_e - self._encode(upd)              # subtract applied

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import count_sketch as cs
+from repro.kernels import ops as kops
 
 Array = jax.Array
 
@@ -34,13 +35,17 @@ _CHUNK = 1 << 22  # coords per selection chunk (hierarchical top-k)
 
 def heavymix(cfg: cs.SketchConfig, sketch: Array, k: int, d: int, *,
              key: Array | None = None, faithful: bool = False,
-             estimates: Array | None = None) -> tuple[Array, Array]:
+             estimates: Array | None = None,
+             use_pallas: bool | None = None) -> tuple[Array, Array]:
     """Select k indices from a summed sketch. Returns (idx (k,), est (k,)).
 
     faithful=True pads the heavy set with uniformly random non-heavy
     coordinates exactly as Alg. 2; the default pads with the next-largest
     estimates instead. If ``estimates`` is given (precomputed, e.g. by the
-    Pallas decode kernel) the internal decode is skipped.
+    'ts' sketch's own decode) the internal decode is skipped. The
+    estimates come from ``kernels.ops.decode``, whose dispatch
+    (``use_pallas``, ``kernels.dispatch``) runs the Pallas decode kernel on
+    TPU and the jnp gather elsewhere; the two agree bit for bit.
 
     For d beyond ~4M coords the selection runs *hierarchically*: decode and
     top-k per chunk inside a scan, then a final top-k over the union of the
@@ -52,9 +57,10 @@ def heavymix(cfg: cs.SketchConfig, sketch: Array, k: int, d: int, *,
     threshold, scores and top-k under ``recover/select``.
     """
     if estimates is None and not faithful and d > _CHUNK and d > 4 * k:
-        return _heavymix_chunked(cfg, sketch, k, d)
+        return _heavymix_chunked(cfg, sketch, k, d, use_pallas=use_pallas)
     with jax.named_scope("recover/decode"):
-        est = cs.decode(cfg, sketch, d) if estimates is None else estimates
+        est = (kops.decode(cfg, sketch, d, use_pallas=use_pallas)
+               if estimates is None else estimates)
     with jax.named_scope("recover/select"):
         l2sq = cs.l2sq_estimate(sketch)
         heavy = est * est >= l2sq / k  # (alpha, l2)-heavy coordinates
@@ -71,13 +77,16 @@ def heavymix(cfg: cs.SketchConfig, sketch: Array, k: int, d: int, *,
         return idx, est[idx]
 
 
-def _heavymix_chunked(cfg: cs.SketchConfig, sketch: Array, k: int,
-                      d: int) -> tuple[Array, Array]:
+def _heavymix_chunked(cfg: cs.SketchConfig, sketch: Array, k: int, d: int,
+                      *, use_pallas: bool | None = None
+                      ) -> tuple[Array, Array]:
     """Greedy-fill HEAVYMIX with chunked decode + hierarchical top-k.
 
     Greedy fill orders by |estimate|, and the heavy set H is exactly the
     top-|H| by |estimate| (heaviness is a threshold on est^2), so a plain
     top-k by |est| selects H ∪ greedy fill — no heavy-boost term needed.
+    Every chunk decodes through one compiled decode, its base traced; the
+    coordinates past ``d`` in the last chunk are not estimated.
     """
     with jax.named_scope("recover/decode"):
         sk = sketch.astype(jnp.float32)
@@ -85,13 +94,12 @@ def _heavymix_chunked(cfg: cs.SketchConfig, sketch: Array, k: int,
     k_c = min(k, _CHUNK)
 
     def body(_, i):
+        base = i * _CHUNK
         with jax.named_scope("recover/decode"):
-            base = i * _CHUNK
-            idx = jnp.arange(_CHUNK) + base
-            buckets, signs = cs.hash_buckets(cfg, idx)
-            est = jnp.median(
-                jnp.take_along_axis(sk, buckets, axis=1) * signs, axis=0)
+            est = kops.decode(cfg, sk, _CHUNK, offset=base, limit=d,
+                              use_pallas=use_pallas)
         with jax.named_scope("recover/select"):
+            idx = jnp.arange(_CHUNK) + base
             score = jnp.where(idx < d, jnp.abs(est), -1.0)  # tail padding
             v, loc = jax.lax.top_k(score, k_c)
             return None, (v, loc + base, est[loc])

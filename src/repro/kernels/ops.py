@@ -47,18 +47,22 @@ def encode(cfg: SketchConfig, g: Array, *, offset: int = 0,
     return ref.count_sketch_encode(cfg, g.reshape(-1), offset=int(offset))
 
 
-def decode(cfg: SketchConfig, sketch: Array, d: int, *, offset: int = 0,
+def decode(cfg: SketchConfig, sketch: Array, d: int, *,
+           offset: int | Array = 0, limit: int | None = None,
            use_pallas: bool | None = None,
            interpret: bool | None = None) -> Array:
     """Count-Sketch decode: (rows, width) -> (d,) coordinate estimates.
 
     ``offset`` estimates coordinates [offset, offset + d) — the partial
-    decode matching a partial encode."""
+    decode matching a partial encode; it may be traced (a scan's chunk
+    base). ``limit``: coordinates at or past it read 0 (the Pallas kernel
+    skips the blocks wholly past it)."""
     pallas, interp = _resolve(use_pallas, interpret)
     if pallas:
-        return _pallas_decode(cfg, sketch, d, index_offset=int(offset),
-                              interpret=interp)
-    return ref.count_sketch_decode(cfg, sketch, d, offset=int(offset))
+        return _pallas_decode(cfg, sketch, d, index_offset=offset,
+                              limit=limit, interpret=interp)
+    return ref.count_sketch_decode(cfg, sketch, d, offset=offset,
+                                   limit=limit)
 
 
 def heavymix_recover(cfg: SketchConfig, sketch: Array, k: int, d: int, *,

@@ -27,15 +27,21 @@ def count_sketch_encode(cfg: cs.SketchConfig, g: Array,
 
 
 def count_sketch_decode(cfg: cs.SketchConfig, sketch: Array, d: int,
-                        offset: int = 0) -> Array:
+                        offset: int | Array = 0,
+                        limit: int | None = None) -> Array:
     """(R, W) -> (d,) median-of-rows estimates. Oracle for kernels.sketch_decode.
 
-    ``offset`` estimates coordinates [offset, offset + d) — the gather-style
-    partial decode matching the partial encode above.
+    ``offset`` (an int or a traced scalar) estimates coordinates
+    [offset, offset + d) — the gather-style partial decode matching the
+    partial encode above. Coordinates at or past ``limit`` read 0.
     """
-    if offset:
-        return cs.decode_at(cfg, sketch, jnp.arange(d) + int(offset))
-    return cs.decode(cfg, sketch, d)
+    if isinstance(offset, int) and not offset:
+        est = cs.decode(cfg, sketch, d)
+    else:
+        est = cs.decode_at(cfg, sketch, jnp.arange(d) + offset)
+    if limit is not None:
+        est = jnp.where(jnp.arange(d) + offset < limit, est, 0.0)
+    return est
 
 
 def heavymix_recover(cfg: cs.SketchConfig, sketch: Array, k: int,
@@ -43,7 +49,7 @@ def heavymix_recover(cfg: cs.SketchConfig, sketch: Array, k: int,
     """Greedy-fill HEAVYMIX selection (idx, est). Oracle for the fused
     Pallas decode+score recovery kernel (kernels.heavymix_topk)."""
     from repro.core import heavymix as hm
-    return hm.heavymix(cfg, sketch, k, d)
+    return hm.heavymix(cfg, sketch, k, d, use_pallas=False)
 
 
 def count_sketch_encode_onehot(cfg: cs.SketchConfig, g: Array) -> Array:

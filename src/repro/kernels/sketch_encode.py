@@ -100,8 +100,9 @@ def signed_onehot(hash_ref, r: int, idx: Array, col: Array,
     return jnp.where(bucket == col, sign, 0.0).astype(jnp.bfloat16)
 
 
-def split_bf16(x: Array) -> Array:
-    """``(1, n)`` f32 -> ``(3, n)`` bf16 rows that sum exactly to ``x``.
+def split_bf16(x: Array, axis: int = 0) -> Array:
+    """f32 -> three bf16 parts that sum exactly to ``x``, stacked along
+    ``axis``: ``(1, n)`` -> ``(3, n)`` rows by default.
 
     Each part takes the next 8 significant bits of the remainder (bf16
     keeps f32's exponent range), so three parts hold all 24 bits of an
@@ -110,12 +111,12 @@ def split_bf16(x: Array) -> Array:
     rest = x - hi.astype(jnp.float32)
     mid = rest.astype(jnp.bfloat16)
     lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    return jnp.concatenate([hi, mid, lo], axis=0)
+    return jnp.concatenate([hi, mid, lo], axis=axis)
 
 
 LANES = 128   # W_lo: the buckets of one hi row
 HI_TILE = 16  # hi rows come in whole packed-bf16 sublane tiles
-_LO_BITS = LANES.bit_length() - 1
+LO_BITS = LANES.bit_length() - 1
 
 
 def _encode_kernel(hash_ref, g_ref, out_ref, *, rows: int, block_d: int,
@@ -139,7 +140,7 @@ def _encode_kernel(hash_ref, g_ref, out_ref, *, rows: int, block_d: int,
         bucket, sign = hash_row(hash_ref, r, idx, shift)
         bucket = bucket.astype(jnp.int32)  # < 2^31: shift >= 1
         parts = split_bf16(sign * g).astype(jnp.float32)  # (3, block_d)
-        on_hi = (bucket >> _LO_BITS) == hi_ids  # (block_h, block_d)
+        on_hi = (bucket >> LO_BITS) == hi_ids  # (block_h, block_d)
         a_t = jnp.concatenate(
             [jnp.where(on_hi, parts[k:k + 1], 0.0) for k in range(3)],
             axis=0).astype(jnp.bfloat16)  # (3 * block_h, block_d)

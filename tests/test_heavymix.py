@@ -88,3 +88,24 @@ def test_workers_select_identical_indices():
     sels = [hm.heavymix(CFG, summed, 32, g.shape[0])[0] for _ in range(4)]
     for s in sels[1:]:
         np.testing.assert_array_equal(np.asarray(sels[0]), np.asarray(s))
+
+
+@pytest.mark.parametrize("path", ["chunked", "flat", "faithful"])
+def test_pallas_decode_selects_as_jnp(monkeypatch, path):
+    """HEAVYMIX through the Pallas decode kernel (interpret mode) selects
+    the indices, with the estimates, of the jnp gather. ``_CHUNK`` is cut
+    so that the chunked path runs its traced chunk base and a ragged
+    last chunk whose second block lies wholly past d."""
+    monkeypatch.setattr(hm, "_CHUNK", 4096)
+    d = 3 * 4096 + 1000 if path == "chunked" else 4000
+    key = jax.random.PRNGKey(5)
+    g = 0.01 * jax.random.normal(key, (d,))
+    hot = jax.random.choice(jax.random.fold_in(key, 6), d, (40,),
+                            replace=False)
+    g = g.at[hot].set(20.0)
+    sk = cs.encode(CFG, g)
+    kw = dict(key=jax.random.PRNGKey(9), faithful=path == "faithful")
+    idx_p, est_p = hm.heavymix(CFG, sk, 64, d, use_pallas=True, **kw)
+    idx_j, est_j = hm.heavymix(CFG, sk, 64, d, use_pallas=False, **kw)
+    np.testing.assert_array_equal(np.asarray(idx_p), np.asarray(idx_j))
+    np.testing.assert_array_equal(np.asarray(est_p), np.asarray(est_j))

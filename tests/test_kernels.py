@@ -217,6 +217,68 @@ def test_decode_matches_ref(d, rows):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("width", [64, 512, 16384, 65536])
+@pytest.mark.parametrize("rows", [1, 4, 5])
+def test_factored_decode_bit_identical(rows, width):
+    """The factored decode (one-hot of the high bits on the MXU, the low
+    bits picked on the vector unit) gives the gather's estimates bit for
+    bit: under one hi row (64), one tile, one 128-row pass and four
+    passes (65536), for a d that is no multiple of the block."""
+    cfg = SketchConfig(rows=rows, width=width, seed=rows + width)
+    d = 5000
+    g = jax.random.normal(jax.random.PRNGKey(width + rows), (d,))
+    sk = ref.count_sketch_encode(cfg, g)
+    out = sketch_decode(cfg, sk, d, interpret=True)
+    want = ref.count_sketch_decode(cfg, sk, d)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_decode_traced_offset_under_jit_and_scan():
+    """``index_offset`` may be traced: under ``jit`` and as a scan's
+    chunk base, one compiled kernel decodes every chunk as the gather
+    does, and ``limit`` zeroes the tail (the last chunk's second block
+    lies wholly past it and is skipped)."""
+    cfg = SketchConfig(rows=5, width=1024, seed=2)
+    d, chunk = 9000, 4096
+    g = jax.random.normal(jax.random.PRNGKey(4), (d,))
+    sk = ref.count_sketch_encode(cfg, g)
+
+    jitted = jax.jit(lambda s, o: sketch_decode(cfg, s, 700, index_offset=o,
+                                                interpret=True))
+    np.testing.assert_array_equal(
+        np.asarray(jitted(sk, jnp.int32(1000))),
+        np.asarray(ref.count_sketch_decode(cfg, sk, 700, offset=1000)))
+
+    def body(_, i):
+        base = i * chunk
+        return None, (sketch_decode(cfg, sk, chunk, index_offset=base,
+                                    limit=d, interpret=True),
+                      ref.count_sketch_decode(cfg, sk, chunk, offset=base,
+                                              limit=d))
+
+    _, (out, want) = jax.jit(
+        lambda: jax.lax.scan(body, None, jnp.arange(3)))()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    flat = np.asarray(out).reshape(-1)
+    np.testing.assert_array_equal(
+        flat[:d], np.asarray(ref.count_sketch_decode(cfg, sk, d)))
+    assert not flat[d:].any()
+
+
+def test_decode_vmap_two_sketches():
+    """Two workers' sketches under ``jax.vmap``: each decodes as alone."""
+    cfg = SketchConfig(rows=5, width=2048, seed=7)
+    d = 3000
+    g = jax.random.normal(jax.random.PRNGKey(6), (2, d))
+    sks = jnp.stack([ref.count_sketch_encode(cfg, x) for x in g])
+    out = jax.vmap(lambda s: sketch_decode(
+        cfg, s, d, index_offset=jnp.int32(77), interpret=True))(sks)
+    for w in range(2):
+        np.testing.assert_array_equal(
+            np.asarray(out[w]),
+            np.asarray(ref.count_sketch_decode(cfg, sks[w], d, offset=77)))
+
+
 def test_encode_decode_roundtrip_recovers_heavy():
     cfg = SketchConfig(rows=5, width=2048, seed=4)
     g = jnp.zeros(16384).at[7777].set(500.0)

@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import heavymix as hm
 from repro.core.count_sketch import SketchConfig
+from repro.kernels import dispatch, ops
 from repro.kernels.heavymix_topk import heavymix_scores
 from repro.kernels.sketch_decode import sketch_decode
 from repro.kernels.sketch_encode import sketch_encode
@@ -85,3 +87,26 @@ def test_encode_at_cell_size_is_one_named_kernel(one_chip):
                        r"\"tpu_custom_call\"", text, flags=re.M)
     assert len(calls) == 1, calls
     assert "sketch_encode" in calls[0], calls
+
+
+def test_heavymix_at_cell_size_decodes_in_the_kernel(one_chip, monkeypatch):
+    """HEAVYMIX for two vmapped workers at the benchmark's flat size takes
+    its estimates from the Mosaic decode kernel, which keeps the name
+    ``sketch_decode``, and no gather is left in ``recover/decode``. The
+    ops layer dispatches as on a TPU (the test process's backend is the
+    CPU, whose Pallas default is the interpreter)."""
+    monkeypatch.setattr(ops, "_resolve", lambda use_pallas, interpret: (
+        dispatch.resolve_dispatch("tpu", use_pallas=use_pallas,
+                                  interpret=interpret)))
+    sk = jax.ShapeDtypeStruct((2, CFG.rows, CFG.width), jnp.float32,
+                              sharding=one_chip)
+    fn = jax.vmap(lambda s: hm.heavymix(CFG, s, k=302_014, d=CELL_D,
+                                        use_pallas=True))
+    text = jax.jit(fn).lower(sk).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, flags=re.M)
+    assert any("sketch_decode" in c for c in calls), calls
+    gathers = [m.group(1) for m in re.finditer(
+        r"= \S+ gather\(.*?op_name=\"([^\"]*)\"", text)]
+    assert gathers, "the selection's gathers should be found"
+    assert not [g for g in gathers if "recover/decode" in g], gathers
